@@ -1056,6 +1056,9 @@ def main() -> None:
                          "and write Chrome-trace-event JSON here (open in "
                          "Perfetto); empty = tracing off")
     args = ap.parse_args()
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     wanted = [s for s in (args.only or "").split(",") if s]
     unwanted = [s for s in (args.skip or "").split(",") if s]
     if args.trace_out:

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import pcast_varying, shard_map
-
 __all__ = ["allgather_matmul", "reduce_scatter_matmul"]
 
 
@@ -55,13 +53,14 @@ def allgather_matmul(x, w, mesh, *, axis: str = "model"):
             )
             return y, blk
 
-        y0 = pcast_varying(
-            jnp.zeros((m_loc * n, w_loc.shape[-1]), x_loc.dtype), (axis,)
+        y0 = jax.lax.pcast(
+            jnp.zeros((m_loc * n, w_loc.shape[-1]), x_loc.dtype), (axis,),
+            to="varying",
         )
         y, _ = jax.lax.fori_loop(0, n, step, (y0, x_loc))
         return y
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(None, axis)),
         out_specs=P(None, axis),
@@ -94,12 +93,13 @@ def reduce_scatter_matmul(x, w, mesh, *, axis: str = "model"):
             blk = jax.lax.dynamic_slice_in_dim(x_loc, c * chunk, chunk, axis=0)
             return acc + jnp.einsum("mk,kn->mn", blk, w_loc)
 
-        acc0 = pcast_varying(
-            jnp.zeros((chunk, w_loc.shape[-1]), x_loc.dtype), (axis,)
+        acc0 = jax.lax.pcast(
+            jnp.zeros((chunk, w_loc.shape[-1]), x_loc.dtype), (axis,),
+            to="varying",
         )
         return jax.lax.fori_loop(0, n, step, acc0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, axis), P(axis, None)),
         out_specs=P(axis, None),

@@ -60,7 +60,6 @@ from repro.core.message_passing import (
 )
 from repro.core import scheduler as sched
 from repro.core.quantization import QuantParams, dequantize, quantize
-from repro.distributed.compat import shard_map
 from repro.graphs.csr import Graph
 from repro.observe import trace as otrace
 
@@ -605,7 +604,16 @@ def _make_shard_map_fn(
             return out
 
         tail = xpad.shape[1:]
-        m = jnp.zeros((l_pad + 1,) + tail, jnp.float32)
+
+        def zeros_acc():
+            # each device scatters into its own accumulator: varying over
+            # "shard", as the scan carry must be
+            return jax.lax.pcast(
+                jnp.zeros((l_pad + 1,) + tail, jnp.float32), ("shard",),
+                to="varying",
+            )
+
+        m = zeros_acc()
         if state.overlap and len(groups_t) == 2:
             # interior first on [owned | zeros]: no data dependency on the
             # all-gather, so the collective overlaps the interior scan;
@@ -615,7 +623,7 @@ def _make_shard_map_fn(
                 [xpad, jnp.zeros((h_max,) + tail, xpad.dtype)], axis=0
             )
             for tag in tags:
-                acc = jnp.zeros((l_pad + 1,) + tail, jnp.float32)
+                acc = zeros_acc()
                 if tag in groups_t[0]:
                     acc = run(groups_t[0][tag], xin_for(tag, xl_int), acc)
                 if tag in groups_t[1]:
@@ -623,7 +631,7 @@ def _make_shard_map_fn(
                 m = m + acc
         else:
             for tag in tags:
-                acc = jnp.zeros((l_pad + 1,) + tail, jnp.float32)
+                acc = zeros_acc()
                 if tag in groups_t[0]:
                     acc = run(groups_t[0][tag], xin_for(tag, xl_full), acc)
                 m = m + acc
@@ -641,7 +649,7 @@ def _make_shard_map_fn(
     if with_coeff:
         in_specs.append(P("shard", *([None] * coeff_ndim)))
     in_specs.extend([P("shard", None, None)] * n_tile_arrays)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -698,6 +706,13 @@ class ShardedAmpleEngine(AmpleEngine):
                     f"mesh has {mesh.devices.size} devices but the plan has "
                     f"{plan.num_shards} shards"
                 )
+            # The SPMD program's output is sliced and gathered eagerly on
+            # return; that needs Auto axes (an Explicit-axis mesh, the
+            # jax.make_mesh default, would ask every such op for a sharding).
+            mesh = jax.sharding.Mesh(
+                mesh.devices, mesh.axis_names,
+                axis_types=(jax.sharding.AxisType.Auto,),
+            )
         if halo_overlap and plan.cfg.use_kernel:
             raise ValueError(
                 "halo_overlap needs the jnp aggregation path (the fused "
@@ -722,6 +737,9 @@ class ShardedAmpleEngine(AmpleEngine):
         self.halo_stats: Dict[str, float] = {}
         #: set per request by the serving layer so halo spans join the trace
         self.trace_id: str = ""
+        #: mesh backend: the device holding each shard's output block in the
+        #: most recent SPMD call, in shard order (empty on the host loop)
+        self.shard_devices: Tuple[str, ...] = ()
 
     def plans(self, mode: str):
         raise NotImplementedError(
@@ -961,6 +979,8 @@ class ShardedAmpleEngine(AmpleEngine):
             )
             args.append(padded[jnp.asarray(state.edge_gather)])
         out = fn(*args, *tile_args)
+        blocks = sorted(out.addressable_shards, key=lambda b: b.index[0].start or 0)
+        self.shard_devices = tuple(str(b.device) for b in blocks)
         parts = [
             out[k * state.p_max : k * state.p_max + owned]
             for k, owned in enumerate(state.owned)
@@ -991,6 +1011,7 @@ class ShardedAmpleEngine(AmpleEngine):
             "halo_per_shard": [s.halo_size for s in splan.shards],
             "edges_per_shard": [s.num_edges for s in splan.shards],
             "owned_per_shard": [s.num_owned for s in splan.shards],
+            "shard_devices": list(self.shard_devices),
         }
 
 

@@ -1,4 +1,8 @@
-"""jit'd public wrapper for the int8 FTE kernel (auto interpret off-TPU)."""
+"""jit'd public wrapper for the int8 FTE kernel.
+
+``interpret=None`` compiles the kernel on a TPU and interprets it on the CPU
+(``kernels.backend.resolve_interpret``).
+"""
 from __future__ import annotations
 
 import functools
@@ -6,6 +10,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.quant_matmul.quant_matmul import quant_matmul_kernel_call
 from repro.kernels.quant_matmul.repack import (
     RepackedWeight,
@@ -25,10 +30,10 @@ __all__ = [
 def quant_matmul(
     a_q: jnp.ndarray, b_q: jnp.ndarray, *, interpret: bool | None = None
 ) -> jnp.ndarray:
-    """int32 = int8 @ int8; Pallas on TPU, interpret elsewhere."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return quant_matmul_kernel_call(a_q, b_q, interpret=interpret)
+    """int32 = int8 @ int8 through the Pallas kernel."""
+    return quant_matmul_kernel_call(
+        a_q, b_q, interpret=resolve_interpret(interpret)
+    )
 
 
 def quant_matmul_repacked(
@@ -40,6 +45,6 @@ def quant_matmul_repacked(
     """int32 = int8 @ repacked int8 weight — bitwise == ``quant_matmul``
     on the unpacked layout (same blocks, integer accumulation), minus the
     per-call weight pad/transpose."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return quant_matmul_repacked_call(a_q, packed, interpret=interpret)
+    return quant_matmul_repacked_call(
+        a_q, packed, interpret=resolve_interpret(interpret)
+    )

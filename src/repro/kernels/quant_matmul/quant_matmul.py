@@ -31,10 +31,9 @@ def _kernel(a_ref, b_ref, out_ref, acc_ref):
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # int8 operands straight into the MXU; Mosaic has no int32 matmul.
     acc_ref[...] += jnp.dot(
-        a_ref[...].astype(jnp.int32),
-        b_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32,
+        a_ref[...], b_ref[...], preferred_element_type=jnp.int32
     )
 
     @pl.when(k == nk - 1)

@@ -73,9 +73,7 @@ def _kernel(a_ref, b_ref, out_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        a_ref[...].astype(jnp.int32),
-        b_ref[0, 0].astype(jnp.int32),
-        preferred_element_type=jnp.int32,
+        a_ref[...], b_ref[0, 0], preferred_element_type=jnp.int32
     )
 
     @pl.when(k == nk - 1)

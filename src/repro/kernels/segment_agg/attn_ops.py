@@ -16,9 +16,13 @@ float re-association of summing tiles in a different grouping than the
 oracle's two global passes).
 
 ``aggregate_tiles_mh`` is the multi-head analogue of ``ops.aggregate_tiles``
-for already-normalized per-head coefficients. Both fall back to interpret
-mode automatically off-TPU. Head packing pads dh up to a 128-lane multiple
-so each DMA'd row is MXU/VPU aligned; the pad is sliced off after combine.
+for already-normalized per-head coefficients. Both run the kernel one group
+of tiles at a time (``segment_agg.scan_tile_groups``); the fused path takes
+the node max M from the tile-layout scores up front, so each group's
+partials rescale and scatter-add at once and no group's partials outlive
+its step. ``interpret=None`` compiles on a TPU and interprets on the CPU.
+Head packing pads dh up to a 128-lane multiple so each DMA'd row is MXU/VPU
+aligned; the pad is sliced off after combine.
 """
 from __future__ import annotations
 
@@ -27,16 +31,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.segment_agg.attn_kernel import (
     fused_attention_tiles,
     gather_weighted_tiles_mh,
 )
+from repro.kernels.segment_agg.segment_agg import pad_rows, scan_tile_groups
 
 __all__ = ["attend_tiles", "aggregate_tiles_mh", "combine_attention", "pack_heads"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _dhp(dh: int) -> int:
@@ -52,6 +54,27 @@ def pack_heads(z: jnp.ndarray) -> jnp.ndarray:
     return z.reshape(n, h * dhp)
 
 
+def _finite_or_zero(big_m: jnp.ndarray) -> jnp.ndarray:
+    return jnp.where(jnp.isfinite(big_m), big_m, 0.0)
+
+
+def _rescaled_add(acc, m, l, a, out_node, big_m):
+    """Scatter-add one set of tile partials, each rescaled to the node max."""
+    big_l, big_a = acc
+    t, s, h = m.shape
+    flat = out_node.reshape(t * s)
+    # Empty-segment partials carry m = −inf → scale 0, so they vanish here.
+    scale = jnp.exp(m.reshape(t * s, h) - big_m[flat])
+    big_l = big_l.at[flat].add(l.reshape(t * s, h) * scale)
+    big_a = big_a.at[flat].add(a.reshape(t * s, h, -1) * scale[:, :, None])
+    return big_l, big_a
+
+
+def _normalize(big_l, big_a, *, num_nodes: int, dh: int) -> jnp.ndarray:
+    denom = jnp.where(big_l > 0, big_l, 1.0)
+    return (big_a / denom[:, :, None])[:num_nodes, :, :dh]
+
+
 def combine_attention(
     m: jnp.ndarray,  # f32[T, S, H]
     l: jnp.ndarray,  # f32[T, S, H]
@@ -63,22 +86,15 @@ def combine_attention(
 ) -> jnp.ndarray:
     """Cross-tile log-sum-exp combine → f32[num_nodes, H, dh]."""
     t, s, h = m.shape
-    flat = out_node.reshape(t * s)
-    mf = m.reshape(t * s, h)
-    big_m = jnp.full((num_nodes + 1, h), -jnp.inf).at[flat].max(mf)
-    big_m = jnp.where(jnp.isfinite(big_m), big_m, 0.0)
-    # Empty-segment partials carry m = −inf → scale 0, so they vanish here.
-    scale = jnp.exp(mf - big_m[flat])
-    big_l = (
-        jnp.zeros((num_nodes + 1, h)).at[flat].add(l.reshape(t * s, h) * scale)
+    big_m = jnp.full((num_nodes + 1, h), -jnp.inf).at[out_node.reshape(t * s)].max(
+        m.reshape(t * s, h)
     )
-    big_a = (
-        jnp.zeros((num_nodes + 1, h, a.shape[-1]))
-        .at[flat]
-        .add(a.reshape(t * s, h, -1) * scale[:, :, None])
+    zeros = (
+        jnp.zeros((num_nodes + 1, h)),
+        jnp.zeros((num_nodes + 1, h, a.shape[-1])),
     )
-    denom = jnp.where(big_l > 0, big_l, 1.0)
-    return (big_a / denom[:, :, None])[:num_nodes, :, :dh]
+    big_l, big_a = _rescaled_add(zeros, m, l, a, out_node, _finite_or_zero(big_m))
+    return _normalize(big_l, big_a, num_nodes=num_nodes, dh=dh)
 
 
 @functools.partial(
@@ -102,29 +118,39 @@ def attend_tiles(
 
     Returns f32[num_nodes, H, dh].
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     n, h, dh = z.shape
-    xp = pack_heads(z)
-    m, l, a = fused_attention_tiles(
-        xp,
-        gather_idx,
-        scores_t,
-        coeff,
-        seg_ids,
-        segments_per_tile=segments_per_tile,
-        leaky_slope=leaky_slope,
-        interpret=interpret,
+    x_rows = pad_rows(pack_heads(z), h * _dhp(dh))
+    s = segments_per_tile
+    # Head-major lane operands: one lane-dense [1, E] row per head.
+    sc_h = jnp.swapaxes(scores_t, 1, 2)  # [T, H, E]
+    # The node max over every lane aggregating into the node equals the max
+    # of the kernel's tile-local maxima, so it can be taken up front.
+    act = jnp.where(sc_h >= 0.0, sc_h, leaky_slope * sc_h)
+    lane_node = jnp.take_along_axis(out_node, seg_ids, axis=1)  # [T, E]
+    big_m = jnp.full((num_nodes + 1, h), -jnp.inf).at[lane_node.reshape(-1)].max(
+        jnp.swapaxes(act, 1, 2).reshape(-1, h)
     )
-    t, s, _ = m.shape
-    return combine_attention(
-        m,
-        l,
-        a.reshape(t, s, h, -1),
-        out_node,
-        num_nodes=num_nodes,
-        dh=dh,
+    big_m = _finite_or_zero(big_m)
+
+    def step(acc, gi, sc, cf, si, on):
+        m, l, a = fused_attention_tiles(
+            x_rows, gi, sc, cf, si,
+            segments_per_tile=s, leaky_slope=leaky_slope, interpret=interpret,
+        )
+        g = m.shape[0]
+        return _rescaled_add(acc, m, l, a.reshape(g, s, h, -1), on, big_m)
+
+    big_l, big_a = scan_tile_groups(
+        step,
+        (
+            jnp.zeros((num_nodes + 1, h)),
+            jnp.zeros((num_nodes + 1, h, _dhp(dh))),
+        ),
+        (gather_idx, sc_h, coeff, seg_ids, out_node),
+        (0, -jnp.inf, 0.0, s - 1, num_nodes),
     )
+    return _normalize(big_l, big_a, num_nodes=num_nodes, dh=dh)
 
 
 @functools.partial(
@@ -142,19 +168,22 @@ def aggregate_tiles_mh(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Multi-head event-driven aggregation → f32[num_nodes, H, dh]."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     n, h, dh = x.shape
-    xp = pack_heads(x)
-    parts = gather_weighted_tiles_mh(
-        xp,
-        gather_idx,
-        coeff,
-        seg_ids,
-        segments_per_tile=segments_per_tile,
-        interpret=interpret,
+    d = h * _dhp(dh)
+    x_rows = pad_rows(pack_heads(x), d)
+    s = segments_per_tile
+
+    def step(out, gi, cf, si, on):
+        parts = gather_weighted_tiles_mh(
+            x_rows, gi, cf, si, segments_per_tile=s, interpret=interpret
+        )
+        return out.at[on.reshape(-1)].add(parts.reshape(-1, d))
+
+    out = scan_tile_groups(
+        step,
+        jnp.zeros((num_nodes + 1, d), x.dtype),
+        (gather_idx, jnp.swapaxes(coeff, 1, 2), seg_ids, out_node),
+        (0, 0.0, s - 1, num_nodes),
     )
-    t, s, d = parts.shape
-    out = jnp.zeros((num_nodes + 1, d), x.dtype)
-    out = out.at[out_node.reshape(t * s)].add(parts.reshape(t * s, d))
     return out[:num_nodes].reshape(num_nodes, h, -1)[:, :, :dh]

@@ -97,8 +97,6 @@ def _analyze(lowered, compiled, cfg: ModelConfig, shape_name: str, mesh) -> Dict
     from repro.launch.analytic import analytic_report
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x returns [dict]; newer a dict
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = analyze_collectives(hlo, ring_size=mesh_tp(mesh))
     chips = int(len(mesh.devices.flat))

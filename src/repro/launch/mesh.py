@@ -11,7 +11,7 @@ from typing import Tuple
 
 import jax
 
-__all__ = ["make_production_mesh", "data_axes", "model_axis", "mesh_tp"]
+__all__ = ["make_production_mesh", "auto_mesh", "data_axes", "model_axis", "mesh_tp"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,10 +21,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     if debug:
         shape = tuple(int(x) for x in debug.split("x"))
         axes = ("pod", "data", "model")[-len(shape):]
-        return jax.make_mesh(shape, axes)
+        return auto_mesh(shape, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: shardings are placed by the
+    partitioner from the with_sharding_constraint / in_shardings the code
+    declares (the default Explicit axes would ask every op for one)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def data_axes(mesh) -> Tuple[str, ...]:

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import get_config
+from repro.launch.mesh import auto_mesh
 from repro.models.api import model_init
 from repro.serve.engine import ServeEngine
 
@@ -60,6 +61,15 @@ def serve_lm(cfg, args) -> None:
     print("sample:", out[0, : args.prompt_len + 8].tolist())
 
 
+def shard_mesh(num_shards: int):
+    """One chip per shard: a ``("shard",)`` mesh when ``num_shards`` > 1
+    equals the visible device count, else None (shards then run as a host
+    loop on the default device — the single-device reference path)."""
+    if num_shards <= 1 or num_shards != len(jax.devices()):
+        return None
+    return auto_mesh((num_shards,), ("shard",))
+
+
 def serve_gnn(cfg, args) -> None:
     from repro.graphs import make_dataset
     from repro.serve.gnn_engine import GNNRequest, GNNServeEngine
@@ -69,6 +79,7 @@ def serve_gnn(cfg, args) -> None:
         cfg,
         key=jax.random.PRNGKey(0),
         num_shards=args.num_shards,
+        mesh=shard_mesh(args.num_shards),
         partitioner=args.partitioner or None,
         halo_overlap=True if args.halo_overlap else None,
         feature_budget_bytes=budget or None,
@@ -320,7 +331,8 @@ def main():
     ap.add_argument("--requests", type=int, default=3)
     ap.add_argument("--num-shards", type=int, default=1,
                     help="partition the served graph into this many "
-                         "edge-balanced shards (1 = single-plan path)")
+                         "edge-balanced shards (1 = single-plan path); "
+                         "equal to the device count, one chip per shard")
     ap.add_argument("--partitioner", default="",
                     help="sharded-path partitioner: 'edges' (contiguous "
                          "edge-balanced ranges) or 'mincut' (halo-minimizing "
@@ -382,7 +394,10 @@ def main():
                          "('-' = stdout)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import configure_compile_cache
     from repro.observe import metrics as ometrics, trace as otrace
+
+    configure_compile_cache()
 
     if args.trace_out:
         otrace.enable()

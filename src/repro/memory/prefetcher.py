@@ -458,11 +458,12 @@ class _CacheState:
 
 # ------------------------------------------------------------ staging worker
 class _StagedItem:
-    __slots__ = ("event", "value", "build_ms")
+    __slots__ = ("event", "value", "error", "build_ms")
 
     def __init__(self):
         self.event = threading.Event()
         self.value = None
+        self.error: Optional[BaseException] = None
         self.build_ms = 0.0
 
 
@@ -474,7 +475,9 @@ class _StageWorker:
     ``build_ms`` is the true wall cost of that copy and the consumer's
     event wait is the true stall. Items are one-shot: ``take`` removes the
     key, so a chunk uploaded, evicted, and staged again later gets a fresh
-    build.
+    build. A build that raises hands its exception to the consumer, which
+    re-raises it from ``take``: a failed host gather or device put is an
+    error of the request, not a reason to rebuild the copy inline.
     """
 
     def __init__(self, build_fn: Callable[[tuple], object]):
@@ -503,7 +506,10 @@ class _StageWorker:
         return True
 
     def take(self, key: tuple):
-        """Blocking claim: ``(value, build_ms, wait_ms)`` or None."""
+        """Blocking claim: ``(value, build_ms, wait_ms)`` or None.
+
+        None means nothing was staged under ``key`` (or the worker stopped
+        before building it); a build that raised re-raises here."""
         with self._lock:
             item = self._items.get(key)
         if item is None:
@@ -513,6 +519,8 @@ class _StageWorker:
         wait_ms = (time.perf_counter() - t0) * 1e3
         with self._lock:
             self._items.pop(key, None)
+        if item.error is not None:
+            raise item.error
         if item.value is None:
             return None
         return item.value, item.build_ms, wait_ms
@@ -531,8 +539,8 @@ class _StageWorker:
             t0 = time.perf_counter()
             try:
                 item.value = self._build(key)
-            except Exception:  # consumer falls back to the inline build
-                item.value = None
+            except Exception as e:  # re-raised by the consumer's take()
+                item.error = e
             item.build_ms = (time.perf_counter() - t0) * 1e3
             item.event.set()
 

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 from repro.models.lm.moe import moe_init  # noqa: F401 (same param layout)
 
 __all__ = ["moe_apply_sharded", "sharded_applicable"]
@@ -179,7 +178,7 @@ def moe_apply_sharded(
         }
         shared_arg = params["shared"]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(token_axes, None), P(None, None), expert_specs, shared_specs),

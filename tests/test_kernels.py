@@ -89,3 +89,91 @@ class TestQuantMatmul:
         out = ops.quant_matmul(a, b)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(quant_matmul_ref(a, b)))
         assert int(np.asarray(out)[0, 0]) == k * 128 * 128
+
+
+# ------------------------------------------------------------- tile groups
+def _many_tile_plan(seed: int):
+    """~200 tiles of 8 lanes: more than one kernel call's group of tiles."""
+    from repro.kernels.segment_agg.segment_agg import TILE_GROUP
+
+    g = make_lognormal_graph(220, 8.0, seed=seed)
+    plan = build_edge_tile_plan(g, edges_per_tile=8)
+    assert plan.num_tiles > TILE_GROUP
+    return g, plan
+
+
+def test_segment_agg_spans_tile_groups():
+    from repro.kernels.segment_agg import ops
+    from repro.kernels.segment_agg.ref import aggregate_tiles_ref
+
+    g, plan = _many_tile_plan(1)
+    x = jnp.asarray(
+        np.random.default_rng(0).standard_normal((g.num_nodes, 24)).astype(np.float32)
+    )
+    args = tuple(
+        jnp.asarray(a) for a in (plan.gather_idx, plan.coeff, plan.seg_ids, plan.out_node)
+    )
+    kw = dict(num_nodes=g.num_nodes, segments_per_tile=plan.segments_per_tile)
+    out = ops.aggregate_tiles(x, *args, **kw)
+    ref = aggregate_tiles_ref(x, *args, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+
+
+def test_fused_attention_spans_tile_groups():
+    from repro.kernels.segment_agg import attn_ops
+    from repro.kernels.segment_agg.ref import attend_tiles_ref
+
+    g, plan = _many_tile_plan(2)
+    rng = np.random.default_rng(1)
+    h, dh = 2, 5
+    z = jnp.asarray(rng.standard_normal((g.num_nodes, h, dh)).astype(np.float32))
+    scores = rng.standard_normal((g.num_edges + 1, h)).astype(np.float32)
+    scores[-1] = -np.inf  # padding lanes read -inf
+    eids = np.where(plan.edge_ids < 0, g.num_edges, plan.edge_ids)
+    args = (
+        jnp.asarray(plan.gather_idx),
+        jnp.asarray(scores[eids]),
+        jnp.asarray(plan.coeff),
+        jnp.asarray(plan.seg_ids),
+        jnp.asarray(plan.out_node),
+    )
+    kw = dict(
+        num_nodes=g.num_nodes,
+        segments_per_tile=plan.segments_per_tile,
+        leaky_slope=0.2,
+    )
+    out = attn_ops.attend_tiles(z, *args, **kw)
+    ref = attend_tiles_ref(z, *args, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
+
+
+# ------------------------------------------------------ interpret resolution
+@pytest.mark.parametrize(
+    "backend,expect", [("cpu", True), ("tpu", False), ("gpu", None), ("METAL", None)]
+)
+def test_interpret_resolves_from_backend(monkeypatch, backend, expect):
+    """interpret=None: interpret mode on the CPU only, compiled on a TPU, and
+    any other backend refuses instead of interpreting in silence."""
+    import jax
+
+    from repro.kernels.backend import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if expect is None:
+        with pytest.raises(RuntimeError, match="use_kernel=False"):
+            resolve_interpret(None)
+    else:
+        assert resolve_interpret(None) is expect
+    assert resolve_interpret(True) is True and resolve_interpret(False) is False
+
+
+def test_kernel_wrapper_refuses_non_cpu_backend(monkeypatch):
+    import jax
+
+    from repro.kernels.quant_matmul import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    a = jnp.ones((3, 7), jnp.int8)  # shapes no other test traces
+    b = jnp.ones((7, 5), jnp.int8)
+    with pytest.raises(RuntimeError, match="target the TPU"):
+        ops.quant_matmul(a, b)

@@ -741,3 +741,25 @@ def test_response_and_cache_info_carry_stall_copy_ms():
     assert info["copy_ms"] == pytest.approx(eng.stats["copy_ms"])
     assert info["stall_ms"] == pytest.approx(eng.stats["stall_ms"])
     assert 0.0 <= info["prefetch_overlap"] <= 1.0
+
+
+def test_stage_worker_build_error_reaches_the_consumer():
+    """A staged build that raises is re-raised by the consumer's take():
+    a failed gather or device put must not turn into a silent inline
+    rebuild."""
+    from repro.memory.prefetcher import _StageWorker
+
+    def build(key):
+        if key == ("chunk", 1):
+            raise RuntimeError("device put failed")
+        return key
+
+    worker = _StageWorker(build)
+    try:
+        assert worker.request(("chunk", 0)) and worker.request(("chunk", 1))
+        assert worker.take(("chunk", 0))[0] == ("chunk", 0)
+        with pytest.raises(RuntimeError, match="device put failed"):
+            worker.take(("chunk", 1))
+        assert worker.take(("chunk", 2)) is None  # never staged
+    finally:
+        worker.stop()
