@@ -854,19 +854,15 @@ class AmpleEngine:
         qp = None
         if self.cfg.mixed_precision and "int8" in plans:
             qp = self._activation_qp(None, "agg", make_qp=sf.agg_qp)
-        with otrace.get_recorder().span(
-            f"layer:aggregate:{mode}", cat="engine",
-            trace_id=getattr(sf, "trace_id", ""),
-        ):
-            return aggregate_streamed(
-                sf,
-                plans,
-                schedules,
-                num_nodes=self.graph.num_nodes,
-                mixed=self.cfg.mixed_precision,
-                qp=qp,
-                tiles=tiles,
-            )
+        return aggregate_streamed(
+            sf,
+            plans,
+            schedules,
+            num_nodes=self.graph.num_nodes,
+            mixed=self.cfg.mixed_precision,
+            qp=qp,
+            tiles=tiles,
+        )
 
     def _transform_streamed(
         self,
@@ -924,7 +920,19 @@ class AmpleEngine:
         Multi-head: ``edge_coeff`` f32[E, H] with ``x`` f32[N, H, dh]
         aggregates all heads in one tile scan (each head's column bitwise-
         equal to its solo 1-D run on the jnp path).
+
+        Recorded as one ``age`` span; engines override ``_aggregate``.
         """
+        with otrace.get_recorder().span("age", cat="engine", args={"mode": mode}):
+            return self._aggregate(x, mode=mode, edge_coeff=edge_coeff)
+
+    def _aggregate(
+        self,
+        x: jnp.ndarray,
+        *,
+        mode: str = "sum",
+        edge_coeff: Optional[jnp.ndarray] = None,
+    ) -> jnp.ndarray:
         if isinstance(x, _streamed_features_type()):
             if edge_coeff is not None:
                 raise ValueError(
@@ -1073,7 +1081,22 @@ class AmpleEngine:
         destination nodes, so per-group softmax is exact; the fused path
         matches the oracle to float tolerance (tile-grouped summation
         re-associates), not bitwise.
+
+        Recorded as one ``age`` span; engines override
+        ``_attention_aggregate``.
         """
+        with otrace.get_recorder().span("age", cat="engine", args={"mode": mode}):
+            return self._attention_aggregate(
+                scores, z, mode=mode, leaky_slope=leaky_slope)
+
+    def _attention_aggregate(
+        self,
+        scores: jnp.ndarray,
+        z: jnp.ndarray,
+        *,
+        mode: str = "runtime",
+        leaky_slope: float = 0.2,
+    ) -> jnp.ndarray:
         if isinstance(z, _streamed_features_type()):
             raise ValueError(
                 "attention requires dense embeddings; streamed features "
@@ -1095,7 +1118,7 @@ class AmpleEngine:
         if not self.cfg.use_kernel:
             act = jax.nn.leaky_relu(scores, leaky_slope)
             alpha = self.edge_softmax(act, mode=mode)
-            return self.aggregate(z, mode=mode, edge_coeff=alpha)
+            return self._aggregate(z, mode=mode, edge_coeff=alpha)
 
         from repro.kernels.segment_agg import attn_ops
 
@@ -1165,30 +1188,34 @@ class AmpleEngine:
         group then streams chunk-blocked (1-byte rows, exact int32 matmul)
         and the float-protected block is gathered once — bitwise-identical
         to the dense mixed path (GraphSAGE's φ over stored features).
+
+        Recorded as one ``fte`` span, weight and activation quantization
+        included.
         """
-        if isinstance(h, _streamed_features_type()):
-            return self._transform_streamed(h, w, b, activation)
-        if not self.cfg.mixed_precision:
-            return transform_dense(h, w, b, activation)
-        w_q, w_qp, w_packed = self._weight_q(w)
-        a_qp = None
-        ids = self.node_groups.get("int8")
-        if self._forward_active and ids is not None and ids.size:
-            a_qp = self._activation_qp(
-                lambda: h[jnp.asarray(ids, jnp.int32)], "fte"
+        with otrace.get_recorder().span("fte", cat="engine"):
+            if isinstance(h, _streamed_features_type()):
+                return self._transform_streamed(h, w, b, activation)
+            if not self.cfg.mixed_precision:
+                return transform_dense(h, w, b, activation)
+            w_q, w_qp, w_packed = self._weight_q(w)
+            a_qp = None
+            ids = self.node_groups.get("int8")
+            if self._forward_active and ids is not None and ids.size:
+                a_qp = self._activation_qp(
+                    lambda: h[jnp.asarray(ids, jnp.int32)], "fte"
+                )
+            return transform_mixed_precision(
+                h,
+                self.node_groups,
+                w,
+                b,
+                activation,
+                w_q=w_q,
+                w_qp=w_qp,
+                a_qp=a_qp,
+                use_kernel=self.cfg.use_kernel,
+                w_packed=w_packed,
             )
-        return transform_mixed_precision(
-            h,
-            self.node_groups,
-            w,
-            b,
-            activation,
-            w_q=w_q,
-            w_qp=w_qp,
-            a_qp=a_qp,
-            use_kernel=self.cfg.use_kernel,
-            w_packed=w_packed,
-        )
 
     # ------------------------------------------------------------- metrics
     def occupancy_report(self) -> Dict[str, float]:

@@ -748,7 +748,7 @@ class ShardedAmpleEngine(AmpleEngine):
         )
 
     # ----------------------------------------------------------------- AGE
-    def aggregate(
+    def _aggregate(
         self,
         x: jnp.ndarray,
         *,
@@ -873,7 +873,7 @@ class ShardedAmpleEngine(AmpleEngine):
         denom = jnp.where(denom > 0, denom, 1.0)
         return ex / denom[dst]
 
-    def attention_aggregate(
+    def _attention_aggregate(
         self,
         scores: jnp.ndarray,
         z: jnp.ndarray,
@@ -906,7 +906,7 @@ class ShardedAmpleEngine(AmpleEngine):
             )
         act = jax.nn.leaky_relu(scores, leaky_slope)
         alpha = self.edge_softmax(act, mode=mode)
-        return self.aggregate(z, mode=mode, edge_coeff=alpha)
+        return self._aggregate(z, mode=mode, edge_coeff=alpha)
 
     def _softmax_dplan(self, sp, mode: str, tag: str, plan):
         """Per-shard device plan mirror, shared with sharded_aggregate.

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.message_passing import AmpleEngine, EngineConfig, compile_sharded_plans
 from repro.graphs.csr import Graph, add_self_loops
+from repro.observe import trace as otrace
 
 __all__ = [
     "ArchSpec",
@@ -200,7 +201,13 @@ def gnn_forward(params: Dict, cfg: ModelConfig, batch: Dict) -> Tuple[jnp.ndarra
     from repro.memory.prefetcher import StreamedFeatures
 
     feats = batch["features"]
-    x = feats if isinstance(feats, StreamedFeatures) else jnp.asarray(feats)
+    if isinstance(feats, StreamedFeatures):
+        x = feats
+    else:
+        with otrace.get_recorder().span(
+            "upload", cat="model", args={"bytes": getattr(feats, "nbytes", 0)}
+        ):
+            x = jnp.asarray(feats)
     engine = batch.get("engine")
     n = engine.graph.num_nodes if engine is not None else batch["graph"].num_nodes
     want = cfg.gnn_layer_dims[0]

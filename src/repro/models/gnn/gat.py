@@ -35,6 +35,7 @@ from repro.core.message_passing import AmpleEngine
 from repro.graphs.csr import Graph
 from repro.models.gnn import api
 from repro.models.gnn.layers import glorot
+from repro.observe import trace as otrace
 
 __all__ = ["init", "apply", "reference", "LEAKY_SLOPE"]
 
@@ -86,31 +87,33 @@ def apply(cfg: ModelConfig, params: Dict, engine: AmpleEngine, x: jnp.ndarray) -
     src, dst = engine.edge_endpoints()
     n_layers = len(params["layers"])
     num_nodes = engine.graph.num_nodes
+    rec = otrace.get_recorder()
     for i, lyr in enumerate(params["layers"]):
-        h = _heads(cfg)
-        dh = _head_dim(cfg, i)
-        concat = i < n_layers - 1
-        # φ: one mixed-precision FTE over all heads at once (x may be a
-        # StreamedFeatures handle on the out-of-core first layer; the
-        # projection output is dense either way).
-        z = engine.transform(x, lyr["w"])  # [N, H*dh]
-        zh = z.reshape(num_nodes, h, dh)
-        src_sc = jnp.einsum("nhd,hd->nh", zh, lyr["a_src"])  # [N, H]
-        dst_sc = jnp.einsum("nhd,hd->nh", zh, lyr["a_dst"])  # [N, H]
-        # RAW scores [E, H] — one edge-endpoint gather per layer; LeakyReLU,
-        # softmax and the weighted aggregate all run head-vectorized inside
-        # the engine (one fused Pallas launch per layer under use_kernel).
-        scores = src_sc[src] + dst_sc[dst]
-        out = engine.attention_aggregate(
-            scores, zh, mode=mode, leaky_slope=LEAKY_SLOPE
-        )  # [N, H, dh]
-        x = (
-            out.reshape(num_nodes, h * dh)
-            if concat
-            else out.sum(axis=1) / float(h)
-        )
-        if i < n_layers - 1:
-            x = jax.nn.elu(x)
+        with rec.span("layer", cat="model", args={"index": i}):
+            h = _heads(cfg)
+            dh = _head_dim(cfg, i)
+            concat = i < n_layers - 1
+            # φ: one mixed-precision FTE over all heads at once (x may be a
+            # StreamedFeatures handle on the out-of-core first layer; the
+            # projection output is dense either way).
+            z = engine.transform(x, lyr["w"])  # [N, H*dh]
+            zh = z.reshape(num_nodes, h, dh)
+            src_sc = jnp.einsum("nhd,hd->nh", zh, lyr["a_src"])  # [N, H]
+            dst_sc = jnp.einsum("nhd,hd->nh", zh, lyr["a_dst"])  # [N, H]
+            # RAW scores [E, H] — one edge-endpoint gather per layer; LeakyReLU,
+            # softmax and the weighted aggregate all run head-vectorized inside
+            # the engine (one fused Pallas launch per layer under use_kernel).
+            scores = src_sc[src] + dst_sc[dst]
+            out = engine.attention_aggregate(
+                scores, zh, mode=mode, leaky_slope=LEAKY_SLOPE
+            )  # [N, H, dh]
+            x = (
+                out.reshape(num_nodes, h * dh)
+                if concat
+                else out.sum(axis=1) / float(h)
+            )
+            if i < n_layers - 1:
+                x = jax.nn.elu(x)
     return x
 
 

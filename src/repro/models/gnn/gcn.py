@@ -23,6 +23,7 @@ from repro.core.message_passing import AmpleEngine
 from repro.graphs.csr import Graph, gcn_norm_coeffs
 from repro.models.gnn import api
 from repro.models.gnn.layers import glorot
+from repro.observe import trace as otrace
 
 __all__ = ["init", "apply", "reference"]
 
@@ -41,11 +42,13 @@ def init(cfg: ModelConfig, key) -> Dict:
 def apply(cfg: ModelConfig, params: Dict, engine: AmpleEngine, x: jnp.ndarray) -> jnp.ndarray:
     mode = api.agg_mode(cfg)
     n = len(params["layers"])
+    rec = otrace.get_recorder()
     for i, lyr in enumerate(params["layers"]):
-        m = engine.aggregate(x, mode=mode)
-        x = engine.transform(
-            m, lyr["w"], activation=jax.nn.relu if i < n - 1 else None
-        )
+        with rec.span("layer", cat="model", args={"index": i}):
+            m = engine.aggregate(x, mode=mode)
+            x = engine.transform(
+                m, lyr["w"], activation=jax.nn.relu if i < n - 1 else None
+            )
     return x
 
 

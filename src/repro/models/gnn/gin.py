@@ -21,6 +21,7 @@ from repro.graphs.csr import Graph
 from repro.memory.prefetcher import StreamedFeatures, scale_add_streamed
 from repro.models.gnn import api
 from repro.models.gnn.layers import mlp_init
+from repro.observe import trace as otrace
 
 __all__ = ["init", "apply", "reference"]
 
@@ -53,15 +54,17 @@ def _mlp_through_engine(engine: AmpleEngine, mlp: Dict, h: jnp.ndarray) -> jnp.n
 def apply(cfg: ModelConfig, params: Dict, engine: AmpleEngine, x: jnp.ndarray) -> jnp.ndarray:
     mode = api.agg_mode(cfg)
     n = len(params["layers"])
+    rec = otrace.get_recorder()
     for i, mlp in enumerate(params["layers"]):
-        m = engine.aggregate(x, mode=mode)
-        if isinstance(x, StreamedFeatures):  # out-of-core first layer
-            h = scale_add_streamed(x, 1.0 + params["eps"], m)
-        else:
-            h = (1.0 + params["eps"]) * x + m  # aggregation-side residual
-        x = _mlp_through_engine(engine, mlp, h)
-        if i < n - 1:
-            x = jax.nn.relu(x)
+        with rec.span("layer", cat="model", args={"index": i}):
+            m = engine.aggregate(x, mode=mode)
+            if isinstance(x, StreamedFeatures):  # out-of-core first layer
+                h = scale_add_streamed(x, 1.0 + params["eps"], m)
+            else:
+                h = (1.0 + params["eps"]) * x + m  # aggregation-side residual
+            x = _mlp_through_engine(engine, mlp, h)
+            if i < n - 1:
+                x = jax.nn.relu(x)
     return x
 
 

@@ -20,6 +20,7 @@ from repro.core.message_passing import AmpleEngine
 from repro.graphs.csr import Graph
 from repro.models.gnn import api
 from repro.models.gnn.layers import linear_init
+from repro.observe import trace as otrace
 
 __all__ = ["init", "apply", "reference"]
 
@@ -42,12 +43,14 @@ def init(cfg: ModelConfig, key) -> Dict:
 def apply(cfg: ModelConfig, params: Dict, engine: AmpleEngine, x: jnp.ndarray) -> jnp.ndarray:
     mode = api.agg_mode(cfg)
     n = len(params["layers"])
+    rec = otrace.get_recorder()
     for i, lyr in enumerate(params["layers"]):
-        msgs = engine.transform(x, lyr["w3"]["w"], lyr["w3"]["b"], jax.nn.relu)  # φ
-        m = engine.aggregate(msgs, mode=mode)  # A
-        x = engine.transform(x, lyr["w1"]["w"]) + engine.transform(m, lyr["w2"]["w"])
-        if i < n - 1:
-            x = jax.nn.relu(x)
+        with rec.span("layer", cat="model", args={"index": i}):
+            msgs = engine.transform(x, lyr["w3"]["w"], lyr["w3"]["b"], jax.nn.relu)  # φ
+            m = engine.aggregate(msgs, mode=mode)  # A
+            x = engine.transform(x, lyr["w1"]["w"]) + engine.transform(m, lyr["w2"]["w"])
+            if i < n - 1:
+                x = jax.nn.relu(x)
     return x
 
 

@@ -17,15 +17,24 @@ Chrome trace-event JSON format, which loads directly in Perfetto
 Design constraints (these are load-bearing for the serving hot path):
 
 - **Disabled is free.** The module-level default recorder is disabled; call
-  sites guard with ``rec.enabled`` and :meth:`TraceRecorder.span` returns a
-  shared no-op singleton, so a disabled trace point costs one attribute
-  read and no allocation.
+  sites guard with ``rec.enabled`` and, with no profiler session collecting,
+  :meth:`TraceRecorder.span` returns a shared no-op singleton, so a
+  disabled trace point costs one attribute read, one C-level check and no
+  allocation.
 - **One clock.** All stamps are ``time.perf_counter()`` — the same clock
   the serving stack uses for every lifecycle stamp and duration — so spans
   recorded from any thread land on a single consistent timeline and
   trace-derived sums reconcile with the reported ``*_ms`` fields.
 - **Bounded.** The ring buffer (``collections.deque(maxlen=...)``) evicts
   the oldest spans; ``dropped`` reports how many were lost.
+- **On the profiler's clock too.** While a ``jax.profiler`` session is
+  collecting, every context-manager span also opens a
+  ``jax.profiler.TraceAnnotation`` named ``ample.<name>`` carrying its
+  ``trace_id`` and args as metadata, ring enabled or not: a profiler trace
+  shows the program's spans on the device timeline with no switch. Nesting
+  follows from the thread; each span also records its parent (the span open
+  on the same thread when it was entered) and inherits that parent's
+  ``trace_id`` when it has none of its own.
 """
 from __future__ import annotations
 
@@ -35,6 +44,14 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
+
+#: Prefix of the spans' names in a profiler trace.
+PROFILER_PREFIX = "ample."
+
+#: True while a profiler session collects host events (one C-level call).
+profiling = TraceAnnotation.is_enabled
 
 
 class Span(NamedTuple):
@@ -47,6 +64,8 @@ class Span(NamedTuple):
     t0: float
     t1: float
     args: Optional[Dict[str, Any]]
+    sid: int = 0  # this span's id (0: recorded after the fact)
+    parent: int = 0  # id of the span open on the thread at entry (0: none)
 
     @property
     def dur_ms(self) -> float:
@@ -67,14 +86,29 @@ class _NullSpan:
     def set(self, **_kw) -> "_NullSpan":
         return self
 
+    def stamps(self, t0=None, t1=None) -> "_NullSpan":
+        return self
+
 
 NULL_SPAN = _NullSpan()
 
+_SPAN_IDS = itertools.count(1)
+_OPEN = threading.local()  # .stack: the live spans open on this thread
+
+
+def _open_stack() -> list:
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    return stack
+
 
 class _LiveSpan:
-    """Context manager that stamps enter/exit and commits to the ring."""
+    """Context manager that stamps enter/exit, commits to the ring when it
+    is enabled, and mirrors itself into the profiler while one collects."""
 
-    __slots__ = ("_rec", "name", "cat", "lane", "trace_id", "args", "t0")
+    __slots__ = ("_rec", "name", "cat", "lane", "trace_id", "args", "t0",
+                 "t1", "sid", "parent", "_annotation")
 
     def __init__(self, rec, name, cat, lane, trace_id, args):
         self._rec = rec
@@ -83,22 +117,39 @@ class _LiveSpan:
         self.lane = lane
         self.trace_id = trace_id
         self.args = args
-        self.t0 = 0.0
+        self.t0 = self.t1 = 0.0
+        self.sid = self.parent = 0
+        self._annotation = None
 
     def __enter__(self) -> "_LiveSpan":
+        stack = _open_stack()
+        if stack:
+            up = stack[-1]
+            self.parent = up.sid
+            if not self.trace_id:
+                self.trace_id = up.trace_id
+        self.sid = next(_SPAN_IDS)
+        stack.append(self)
+        if profiling():
+            meta = dict(self.args) if self.args else {}
+            if self.trace_id:
+                meta["trace_id"] = self.trace_id
+            self._annotation = TraceAnnotation(PROFILER_PREFIX + self.name, **meta)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self._rec.add_span(
-            self.name,
-            self.t0,
-            time.perf_counter(),
-            cat=self.cat,
-            lane=self.lane,
-            trace_id=self.trace_id,
-            args=self.args,
-        )
+        if not self.t1:  # not pinned by stamps()
+            self.t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        stack = _open_stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        lane = self.lane if self.lane is not None else threading.current_thread().name
+        self._rec._commit(Span(self.name, self.cat, lane, self.trace_id, self.t0,
+                               self.t1, self.args, self.sid, self.parent))
         return False
 
     def set(self, **kw) -> "_LiveSpan":
@@ -106,6 +157,19 @@ class _LiveSpan:
         if self.args is None:
             self.args = {}
         self.args.update(kw)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**kw)
+        return self
+
+    def stamps(self, t0: Optional[float] = None, t1: Optional[float] = None
+               ) -> "_LiveSpan":
+        """Record ``[t0, t1)`` in the ring in place of the entry and exit
+        times: the accounting's own ``perf_counter`` stamps, so the span
+        reconciles exactly with the ``*_ms`` field measured from them."""
+        if t0 is not None:
+            self.t0 = t0
+        if t1 is not None:
+            self.t1 = t1
         return self
 
 
@@ -130,10 +194,17 @@ class TraceRecorder:
         trace_id: str = "",
         args: Optional[Dict[str, Any]] = None,
     ):
-        """Context manager recording ``[enter, exit)`` as one span."""
-        if not self.enabled:
+        """Context manager recording ``[enter, exit)`` as one span: into the
+        ring when it is enabled, into the profiler while one collects, and
+        ``NULL_SPAN`` when neither is on."""
+        if not (self.enabled or profiling()):
             return NULL_SPAN
         return _LiveSpan(self, name, cat, lane, trace_id, args)
+
+    @property
+    def recording(self) -> bool:
+        """True when a span would land anywhere: the ring or the profiler."""
+        return self.enabled or profiling()
 
     def add_span(
         self,
@@ -157,9 +228,14 @@ class TraceRecorder:
             return
         if lane is None:
             lane = threading.current_thread().name
+        self._commit(Span(name, cat, lane, trace_id, t0, t1, args))
+
+    def _commit(self, span: Span) -> None:
+        if not self.enabled:
+            return
         with self._lock:
             self._added += 1
-            self._ring.append(Span(name, cat, lane, trace_id, t0, t1, args))
+            self._ring.append(span)
 
     def add_instant(
         self,

@@ -795,21 +795,24 @@ class GNNServeEngine:
         if isinstance(engine, ShardedAmpleEngine):
             engine.trace_id = trace_id  # halo spans join this request's trace
             halo_before = dict(engine.halo_stats)
-        t0 = time.perf_counter()
-        y, _ = gnn_api.gnn_forward(
-            self.params, cfg,
-            {"graph": prepared, "features": batch_features, "engine": engine},
-        )
-        y = np.asarray(jax.block_until_ready(y))
-        t1 = time.perf_counter()
-        run_ms = (t1 - t0) * 1e3
         rec = otrace.get_recorder()
-        if rec.enabled:
-            # Same stamps as run_ms, so the execute span reconciles exactly.
-            rec.add_span(
-                "execute", t0, t1, cat="serve", trace_id=trace_id,
-                args={"arch": arch, "streamed": self._last_stream is not None},
+        with rec.span(
+            "execute", cat="serve", trace_id=trace_id,
+            args={"arch": arch, "streamed": self._last_stream is not None},
+        ) as span:
+            t0 = time.perf_counter()
+            y, _ = gnn_api.gnn_forward(
+                self.params, cfg,
+                {"graph": prepared, "features": batch_features, "engine": engine},
             )
+            with rec.span("wait", cat="serve"):
+                y = jax.block_until_ready(y)
+            with rec.span("fetch", cat="serve", args={"bytes": y.nbytes}):
+                y = np.asarray(y)
+            t1 = time.perf_counter()
+            # Same stamps as run_ms, so the execute span reconciles exactly.
+            span.stamps(t0, t1)
+        run_ms = (t1 - t0) * 1e3
         if self._last_stream is not None:
             s = self._last_stream
             self.stats["bytes_streamed"] += s.bytes_streamed
@@ -900,37 +903,43 @@ class GNNServeEngine:
         response's ``queue_ms`` reports the wait between then and execution
         start.
         """
-        arch = self._arch(arch)
-        # The store-cache identity is the CALLER's object: validation may
-        # convert (float64/jnp inputs), and padding copies — keying on either
-        # derived array would rebuild the store on every warm request.
-        original = features
-        features = self._validate_request(graph, features)
         rec = otrace.get_recorder()
-        if rec.enabled and not trace_id:
+        if rec.recording and not trace_id:
             trace_id = otrace.new_trace_id()
-        exec_start = time.perf_counter()
-        queue_ms = self._queue_ms(admitted_at, exec_start)
-        if rec.enabled and admitted_at > 0.0:
-            rec.add_span("queue", admitted_at, exec_start, cat="serve",
-                         trace_id=trace_id)
-        if self.padded_unions:
-            prepared, plan, engine, hit, plan_ms = self._plan_for_padded([graph], arch)
-            features = self._pad_features(features, prepared.num_nodes)
-        elif self.sharded:
-            prepared, plan, engine, hit, plan_ms = self._plan_for_sharded(graph, arch)
-        else:
-            prepared, plan, engine, hit, plan_ms = self._plan_for(graph, arch)
-        if rec.enabled:
-            rec.add_span(
-                "plan", exec_start, time.perf_counter(), cat="serve",
+        with rec.span("request", cat="serve", trace_id=trace_id,
+                      args={"nodes": graph.num_nodes}) as request:
+            arch = self._arch(arch)
+            # The store-cache identity is the CALLER's object: validation may
+            # convert (float64/jnp inputs), and padding copies — keying on
+            # either derived array would rebuild the store on every warm
+            # request.
+            original = features
+            with rec.span("validate", cat="serve"):
+                features = self._validate_request(graph, features)
+            with rec.span("plan", cat="serve") as span:
+                exec_start = time.perf_counter()
+                span.stamps(t0=exec_start)  # the queue span ends here
+                queue_ms = self._queue_ms(admitted_at, exec_start)
+                if admitted_at > 0.0:
+                    rec.add_span("queue", admitted_at, exec_start, cat="serve",
+                                 trace_id=trace_id)
+                if self.padded_unions:
+                    prepared, plan, engine, hit, plan_ms = self._plan_for_padded(
+                        [graph], arch)
+                elif self.sharded:
+                    prepared, plan, engine, hit, plan_ms = self._plan_for_sharded(
+                        graph, arch)
+                else:
+                    prepared, plan, engine, hit, plan_ms = self._plan_for(graph, arch)
+                span.set(cache_hit=hit, plan_ms=plan_ms)
+            request.set(cache_hit=hit, padded_nodes=prepared.num_nodes)
+            if self.padded_unions:
+                with rec.span("pad", cat="serve"):
+                    features = self._pad_features(features, prepared.num_nodes)
+            y, run_ms = self._run(
+                arch, prepared, engine, features, store_key=original,
                 trace_id=trace_id,
-                args={"cache_hit": hit, "plan_ms": plan_ms},
             )
-        y, run_ms = self._run(
-            arch, prepared, engine, features, store_key=original,
-            trace_id=trace_id,
-        )
         self.stats["requests"] += 1
         if self._last_stream is not None:
             self.stats["streamed_requests"] += 1
@@ -968,37 +977,43 @@ class GNNServeEngine:
         """
         if not requests:
             return []
-        arch = self._arch(requests[0].arch)
-        for r in requests[1:]:
-            self._arch(r.arch)  # every request must match this engine's arch
-        feats = [self._validate_request(r.graph, r.features) for r in requests]
         rec = otrace.get_recorder()
-        exec_start = time.perf_counter()
-        queue_waits = [self._queue_ms(r.admitted_at, exec_start) for r in requests]
         batch_tid = requests[0].trace_id
-        if rec.enabled:
-            if not batch_tid:
-                batch_tid = otrace.new_trace_id()
-            # Per-member queue spans carry each request's own id; the
-            # window-level plan/execute spans carry the lead member's.
-            for r in requests:
-                if r.admitted_at > 0.0:
-                    rec.add_span("queue", r.admitted_at, exec_start,
-                                 cat="serve", trace_id=r.trace_id or batch_tid)
-        members = [r.graph for r in requests]
-        prepared, plan, engine, hit, plan_ms = self._plan_for_batch(members, arch)
-        if rec.enabled:
-            rec.add_span(
-                "plan", exec_start, time.perf_counter(), cat="serve",
+        if rec.recording and not batch_tid:
+            batch_tid = otrace.new_trace_id()
+        # Per-member queue spans carry each request's own id; the
+        # window-level request/plan/execute spans carry the lead member's.
+        with rec.span("request", cat="serve", trace_id=batch_tid,
+                      args={"batch": len(requests)}) as request:
+            arch = self._arch(requests[0].arch)
+            for r in requests[1:]:
+                self._arch(r.arch)  # every request must match this engine's arch
+            with rec.span("validate", cat="serve"):
+                feats = [self._validate_request(r.graph, r.features)
+                         for r in requests]
+            with rec.span("plan", cat="serve") as span:
+                exec_start = time.perf_counter()
+                span.stamps(t0=exec_start)  # the queue spans end here
+                queue_waits = [self._queue_ms(r.admitted_at, exec_start)
+                               for r in requests]
+                for r in requests:
+                    if r.admitted_at > 0.0:
+                        rec.add_span("queue", r.admitted_at, exec_start,
+                                     cat="serve", trace_id=r.trace_id or batch_tid)
+                members = [r.graph for r in requests]
+                prepared, plan, engine, hit, plan_ms = self._plan_for_batch(
+                    members, arch)
+                span.set(cache_hit=hit, plan_ms=plan_ms, batch=len(requests))
+            request.set(cache_hit=hit,
+                        nodes=sum(m.num_nodes for m in members),
+                        padded_nodes=prepared.num_nodes)
+            with rec.span("pad", cat="serve"):
+                features = self._pad_features(np.concatenate(feats, axis=0),
+                                              prepared.num_nodes)
+            y, run_ms = self._run(
+                arch, prepared, engine, features, cache_store=False,
                 trace_id=batch_tid,
-                args={"cache_hit": hit, "plan_ms": plan_ms,
-                      "batch": len(requests)},
             )
-        features = self._pad_features(np.concatenate(feats, axis=0), prepared.num_nodes)
-        y, run_ms = self._run(
-            arch, prepared, engine, features, cache_store=False,
-            trace_id=batch_tid,
-        )
         # Counted only on success, so a failed-and-requeued continuous-batching
         # window doesn't double-count when it retries.
         self.stats["requests"] += len(requests)

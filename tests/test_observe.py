@@ -542,3 +542,105 @@ def test_halo_overlap_spans_reconcile_with_response(recorder):
     assert 0.0 <= r.halo_overlap <= 1.0
     # the gather runs on its own lane, apart from the consumer's spans
     assert {s.lane for s in gathers} == {"halo"}
+
+
+# ------------------------------------------------ spans on the profiler
+def _profiled_spans(logdir):
+    """The ``ample.*`` host events of the one xplane under ``logdir``:
+    [(name, start_ns, end_ns, stats, line)]."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{logdir}/plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(otrace.PROFILER_PREFIX):
+                    out.append((e.name[len(otrace.PROFILER_PREFIX):],
+                                int(e.start_ns), int(e.end_ns),
+                                {k: str(v) for k, v in e.stats},
+                                f"{plane.name}/{i}"))
+    return out
+
+
+def test_warm_request_spans_nest_on_the_profilers_clock(tmp_path):
+    """With the ring off, a profiler session alone sees the request's span
+    tree as ``ample.*`` host events, nested in time, under one trace_id."""
+    g = _graph(n=300)
+    eng = GNNServeEngine(_cfg(), key=jax.random.PRNGKey(0),
+                         union_node_bucket=128, union_edge_bucket=512)
+    eng.infer(g, g.features)  # warm: plan cache, activation scales, compiles
+    assert not otrace.is_enabled()
+    with jax.profiler.trace(str(tmp_path)):
+        r = eng.infer(g, g.features)
+    assert r.trace_id and r.cache_hit
+    spans = _profiled_spans(tmp_path)
+    assert {s[3].get("trace_id") for s in spans} == {r.trace_id}
+    assert len({s[4] for s in spans}) == 1  # one thread
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+    tree = {
+        "request": {"validate", "plan", "pad", "execute"},
+        "execute": {"upload", "layer", "wait", "fetch"},
+        "layer": {"fte", "age"},
+    }
+
+    def inside(child, parent):
+        return parent[1] <= child[1] and child[2] <= parent[2]
+
+    for parent, children in tree.items():
+        for child in children:
+            assert by[child], child
+            for c in by[child]:
+                assert any(inside(c, p) for p in by[parent]), (child, parent)
+    assert len(by["request"]) == 1 and len(by["layer"]) == 2
+    assert by["request"][0][3]["padded_nodes"] == "384"
+    assert by["request"][0][3]["cache_hit"] in ("1", "True")
+    # siblings of one parent do not overlap, and run in the served order
+    order = [by[n][0] for n in ("validate", "plan", "pad", "execute")]
+    assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))
+
+
+def test_span_is_null_unless_ring_or_profiler_collects(tmp_path):
+    rec = TraceRecorder(capacity=16, enabled=False)
+    assert not rec.recording and rec.span("a") is NULL_SPAN
+    with jax.profiler.trace(str(tmp_path)):
+        assert rec.recording
+        live = rec.span("b")
+        assert live is not NULL_SPAN
+        with live:
+            pass
+    assert rec.spans() == []  # the ring stays off; the profiler saw it
+    assert [s[0] for s in _profiled_spans(tmp_path)] == ["b"]
+    assert not rec.recording and rec.span("c") is NULL_SPAN
+
+
+def test_ring_spans_record_parent_and_inherit_trace_id():
+    rec = TraceRecorder()
+    with rec.span("outer", trace_id="req-p") as outer:
+        with rec.span("inner") as inner:
+            pass
+        with rec.span("other", trace_id="req-q"):
+            pass
+    with rec.span("next"):
+        pass
+    by = {s.name: s for s in rec.spans()}
+    assert by["inner"].parent == by["outer"].sid == outer.sid
+    assert by["inner"].sid == inner.sid != by["outer"].sid
+    assert by["inner"].trace_id == "req-p"  # inherited from the open span
+    assert by["other"].trace_id == "req-q"  # its own id wins
+    assert by["outer"].parent == 0 and by["next"].parent == 0
+    assert by["next"].trace_id == ""
+
+
+def test_pinned_stamps_replace_entry_and_exit():
+    rec = TraceRecorder()
+    with rec.span("x") as sp:
+        sp.stamps(t0=1.0)
+        sp.stamps(t1=2.5)
+    (s,) = rec.spans()
+    assert (s.t0, s.t1) == (1.0, 2.5)
+    assert NULL_SPAN.stamps(1.0, 2.0) is NULL_SPAN
