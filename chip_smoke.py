@@ -226,7 +226,8 @@ def lowered_kernels(cfg) -> dict:
     d_in, d_hid = cfg.d_model, cfg.d_ff
     i32 = lambda *s: jnp.zeros(s, jnp.int32)
     f32 = lambda *s: jnp.zeros(s, jnp.float32)
-    plan = DeviceTilePlan(i32(t, e), f32(t, e), i32(t, e), i32(t, e), None)
+    plan = DeviceTilePlan(i32(t, e), f32(t, e), i32(t, e), i32(t, e), i32(t),
+                          i32(n), None)
     a_q = jnp.zeros((n, d_in), jnp.int8)
     w_q = jnp.zeros((d_in, d_hid), jnp.int8)
     packed = qm_ops.repack_weight(w_q)

@@ -5,7 +5,8 @@ Three execution paths mirror the paper's comparison:
 * ``aggregate_edge_tiles``  — event-driven path (AMPLE): ``lax.scan`` over the
   planner's dense edge tiles; each step gathers a tile of neighbour embeddings
   (HBM→VMEM stream in the Pallas version), reduces by local segment, and
-  scatter-adds partial results (partial-response combining). Compute ∝ E.
+  adds the partial results into the tile's window of a segment-row
+  accumulator (partial-response combining). Compute ∝ E.
 * ``aggregate_bucket_plan`` — degree-bucketed padding (≤2× waste); the only
   path supporting ``max`` aggregation.
 * ``aggregate_padded_plan`` — HyGCN-style double-buffer baseline, one padded
@@ -21,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Dict, NamedTuple, Optional
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,8 @@ from repro.core.quantization import QuantParams, compute_scale_zp, dequantize, q
 __all__ = [
     "DeviceTilePlan",
     "to_device_plan",
+    "segment_rows",
+    "live_rows",
     "tile_edge_coeff",
     "aggregate_edge_tiles",
     "aggregate_bucket_plan",
@@ -45,7 +48,14 @@ __all__ = [
 
 
 class DeviceTilePlan(NamedTuple):
-    """jnp mirror of scheduler.EdgeTilePlan (leaves scanned over axis 0).
+    """jnp mirror of scheduler.EdgeTilePlan (tile leaves scanned over axis 0).
+
+    ``row_start`` and ``node_row`` place the plan's live segments in
+    *segment-row space* (``segment_rows``): each tile's live segments own the
+    contiguous accumulator rows ``[row_start[t], row_start[t] + k_t)``, so
+    the scans combine a tile's partial results with one window update
+    instead of a scatter into node space, and ``node_row`` gathers the rows
+    back into node order at the end.
 
     ``edge_ids`` is None when the plan was uploaded without the runtime-
     coefficient indirection (static-coeff modes never read it, and the array
@@ -56,17 +66,85 @@ class DeviceTilePlan(NamedTuple):
     coeff: jnp.ndarray  # f32[T, E]
     seg_ids: jnp.ndarray  # int32[T, E]
     out_node: jnp.ndarray  # int32[T, S]
+    row_start: jnp.ndarray  # int32[T]: accumulator row of each tile's segment 0
+    node_row: jnp.ndarray  # int32[num_nodes]: accumulator row of each node
     edge_ids: Optional[jnp.ndarray]  # int32[T, E]; -1 on padding lanes
+
+
+def _tile_rows(out_node: np.ndarray, num_nodes: int):
+    """(live segments per tile, whether segment 0 continues the previous
+    tile's last live node), after checking that live segments are a prefix."""
+    t, s = out_node.shape
+    live = out_node != num_nodes
+    k = live.sum(axis=1)
+    if np.any((out_node < 0) | (out_node > num_nodes)) or not np.array_equal(
+        live, np.arange(s) < k[:, None]
+    ):
+        raise ValueError(
+            "tile plan breaks the segment-row invariant: each tile's live "
+            "segments must be the prefix 0..k-1 of node ids below num_nodes"
+        )
+    last = out_node[np.arange(t), np.maximum(k - 1, 0)]
+    cont = np.zeros(t, bool)
+    cont[1:] = (k[1:] > 0) & (k[:-1] > 0) & (out_node[1:, 0] == last[:-1])
+    return k, cont
+
+
+def live_rows(plan: sched.EdgeTilePlan) -> int:
+    """Accumulator rows a plan's live segments occupy: live segments less
+    continuations (one per node the plan gives any edge)."""
+    k, cont = _tile_rows(np.asarray(plan.out_node), plan.num_nodes)
+    return int(k.sum() - cont.sum())
+
+
+def segment_rows(plan: sched.EdgeTilePlan) -> Tuple[np.ndarray, np.ndarray]:
+    """Host derivation of ``(row_start int32[T], node_row int32[num_nodes])``.
+
+    Rows follow tile order: tile ``t``'s live segments take the rows after
+    tile ``t - 1``'s, except that a segment 0 continuing tile ``t - 1``'s
+    last live node (a node split across consecutive tiles, the planner's
+    partial response) reuses that node's row. Every node the plan covers
+    then owns exactly one row in ``[0, R)``; each uncovered node gets a
+    spare row of its own from ``R + S`` on, beyond any tile's window, so
+    the accumulator needs ``num_nodes + S`` rows.
+
+    Every plan producer keeps the invariant this relies on
+    (``build_edge_tile_plan``, ``concat_tile_plans``, ``split_plan_by_halo``,
+    ``pack_tiles_by_chunk``); a plan that breaks it — a node in two
+    non-adjacent tiles, or twice in one — raises ``ValueError``.
+    """
+    out_node = np.asarray(plan.out_node)
+    num_nodes = plan.num_nodes
+    s = out_node.shape[1]
+    k, cont = _tile_rows(out_node, num_nodes)
+    row_start = np.cumsum(k - cont) - k
+    rows = int(np.sum(k - cont))
+    t_idx, s_idx = np.nonzero(out_node != num_nodes)
+    nodes = out_node[t_idx, s_idx]
+    seg_row = row_start[t_idx] + s_idx
+    node_row = np.full(num_nodes, -1, np.int64)
+    node_row[nodes] = seg_row
+    if not np.array_equal(node_row[nodes], seg_row):
+        raise ValueError(
+            "tile plan breaks the segment-row invariant: a node's segments "
+            "lie in non-adjacent tiles, so it would need two accumulator rows"
+        )
+    free = node_row < 0
+    node_row[free] = rows + s + np.arange(int(free.sum()))
+    return row_start.astype(np.int32), node_row.astype(np.int32)
 
 
 def to_device_plan(
     plan: sched.EdgeTilePlan, *, with_edge_ids: bool = True
 ) -> DeviceTilePlan:
+    row_start, node_row = segment_rows(plan)
     return DeviceTilePlan(
         gather_idx=jnp.asarray(plan.gather_idx, jnp.int32),
         coeff=jnp.asarray(plan.coeff, jnp.float32),
         seg_ids=jnp.asarray(plan.seg_ids, jnp.int32),
         out_node=jnp.asarray(plan.out_node, jnp.int32),
+        row_start=jnp.asarray(row_start),
+        node_row=jnp.asarray(node_row),
         edge_ids=(
             jnp.asarray(plan.edge_ids, jnp.int32) if with_edge_ids else None
         ),
@@ -113,7 +191,7 @@ def aggregate_edge_tiles(
     edge_coeff: Optional[jnp.ndarray] = None,
     out_init: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """Event-driven aggregation: scan tiles, segment-reduce, scatter-add.
+    """Event-driven aggregation: scan tiles, segment-reduce, window-add.
 
     ``use_kernel`` routes the per-tile reduction through the Pallas AGE kernel
     (kernels/segment_agg); the default path is pure jnp and serves as its
@@ -131,7 +209,7 @@ def aggregate_edge_tiles(
     over the head's feature slice, and each head's lane/segment reduction
     order is identical to its solo 1-D run (bitwise per head on this path).
 
-    ``out_init`` (f32[num_nodes, …]) seeds the scatter accumulator instead of
+    ``out_init`` (f32[num_nodes, …]) seeds the accumulator instead of
     zeros — the continuation hook of the split interior/boundary execution
     (``scheduler.split_plan_by_halo``): the boundary scan picks up exactly
     where the interior scan left off, so split == unsplit bitwise. jnp path
@@ -184,31 +262,78 @@ def aggregate_edge_tiles(
             segments_per_tile=segments_per_tile,
         )
 
-    if out_init is None:
-        out = jnp.zeros((num_nodes + 1,) + x.shape[1:], x.dtype)
-    else:
-        # one scratch sentinel row appended; values carry over bitwise
-        out = jnp.concatenate(
-            [
-                out_init.astype(x.dtype),
-                jnp.zeros((1,) + x.shape[1:], x.dtype),
-            ]
-        )
-
-    def body(out, tile):
-        gather_idx, coeff, seg_ids, out_node = tile
+    def partial_sums(gather_idx, coeff, seg_ids):
         gathered = x[gather_idx]  # [E, D] or [E, H, dh]
         cf = coeff.reshape(coeff.shape + (1,) * (gathered.ndim - coeff.ndim))
-        partial_sums = jax.ops.segment_sum(
+        return jax.ops.segment_sum(
             gathered * cf, seg_ids, num_segments=segments_per_tile
         )  # [S, …]
-        out = out.at[out_node].add(partial_sums)
-        return out, None
 
-    out, _ = jax.lax.scan(
-        body, out, (dplan.gather_idx, coeff, dplan.seg_ids, dplan.out_node)
+    return _window_scan(
+        partial_sums,
+        (dplan.gather_idx, coeff, dplan.seg_ids),
+        dplan,
+        num_nodes=num_nodes,
+        segments_per_tile=segments_per_tile,
+        like=x,
+        op="sum",
+        init=out_init,
     )
-    return out[:num_nodes]
+
+
+# op -> (combine, accumulator fill, value masked into sentinel segments)
+_WINDOW_OPS = {
+    "sum": (jnp.add, 0.0, -0.0),
+    "max": (jnp.maximum, -jnp.inf, -jnp.inf),
+}
+
+
+def _window_scan(
+    partial: Callable[..., jnp.ndarray],
+    tiles: tuple,
+    dplan: DeviceTilePlan,
+    *,
+    num_nodes: int,
+    segments_per_tile: int,
+    like: jnp.ndarray,
+    op: str,
+    init: Optional[jnp.ndarray] = None,
+) -> jnp.ndarray:
+    """Scan the tiles, combining each tile's ``[S, …]`` partial results into
+    a window of a segment-row accumulator; return it gathered to node order.
+
+    The accumulator holds ``num_nodes + S`` rows (``segment_rows``), set to
+    the op's identity (0 for ``sum``, −inf for ``max``) or, for the
+    rows of ``init``'s nodes, seeded from ``init`` (the continuation of a
+    split interior/boundary run). Each step reads the S rows at
+    ``row_start[t]``, combines the tile's partials in and writes them back:
+    one contiguous in-place update where a scatter into node space took one
+    row update per segment. Sentinel segments are masked to the exact
+    identity first (−0.0 for ``sum``: ``x + (-0.0) == x`` bitwise for every
+    x), so a non-finite value that only padding lanes read reaches no row,
+    and every row sees the same sequence of operations as the node-space
+    scatter did — the two are bitwise equal.
+    """
+    s = segments_per_tile
+    trailing = like.shape[1:]
+    combine, fill, blank = _WINDOW_OPS[op]
+    acc = jnp.full((num_nodes + s,) + trailing, fill, like.dtype)
+    if init is not None:
+        acc = acc.at[dplan.node_row].set(init.astype(like.dtype))
+
+    def body(acc, tile):
+        *tile_args, out_node, row0 = tile
+        part = partial(*tile_args)
+        live = (out_node != num_nodes).reshape((s,) + (1,) * len(trailing))
+        part = jnp.where(live, part, jnp.asarray(blank, like.dtype))
+        window = jax.lax.dynamic_slice_in_dim(acc, row0, s)
+        acc = jax.lax.dynamic_update_slice_in_dim(
+            acc, combine(window, part), row0, 0
+        )
+        return acc, None
+
+    acc, _ = jax.lax.scan(body, acc, tiles + (dplan.out_node, dplan.row_start))
+    return acc[dplan.node_row]
 
 
 def aggregate_bucket_plan(
@@ -279,25 +404,24 @@ def segment_max_edge_tiles(
 
     The max-shift pass of a numerically stable segment softmax (GAT): scores
     are scattered into tile layout through ``edge_ids`` (padding lanes read
-    −inf), reduced per segment, and combined across split tiles by
-    scatter-max — the partial-response mechanism with max in place of add.
+    −inf), reduced per segment, and combined across split tiles by a window
+    max — the partial-response mechanism with max in place of add.
 
     ``scores`` may be f32[E, H]: all heads reduce in the same scan
     (→ f32[N, H]), each head's column bitwise-equal to its solo 1-D pass.
     """
     sc = tile_edge_coeff(dplan, scores, fill=-jnp.inf)
-    out = jnp.full((num_nodes + 1,) + scores.shape[1:], -jnp.inf, scores.dtype)
-
-    def body(out, tile):
-        sc_t, seg_ids, out_node = tile
-        partial_max = jax.ops.segment_max(
+    return _window_scan(
+        lambda sc_t, seg_ids: jax.ops.segment_max(
             sc_t, seg_ids, num_segments=segments_per_tile
-        )
-        out = out.at[out_node].max(partial_max)
-        return out, None
-
-    out, _ = jax.lax.scan(body, out, (sc, dplan.seg_ids, dplan.out_node))
-    return out[:num_nodes]
+        ),
+        (sc, dplan.seg_ids),
+        dplan,
+        num_nodes=num_nodes,
+        segments_per_tile=segments_per_tile,
+        like=scores,
+        op="max",
+    )
 
 
 @partial(jax.jit, static_argnames=("num_nodes", "segments_per_tile"))
@@ -313,23 +437,22 @@ def edge_segment_sum_tiles(
     The denominator pass of the segment softmax: exp-shifted scores scatter
     through ``edge_ids`` (padding lanes read 0) and accumulate exactly like
     the aggregation scan, so split nodes combine by the same partial-response
-    scatter-add.
+    window add.
 
     ``values`` may be f32[E, H] (→ f32[N, H], one scan for all heads).
     """
     v = tile_edge_coeff(dplan, values, fill=0.0)
-    out = jnp.zeros((num_nodes + 1,) + values.shape[1:], values.dtype)
-
-    def body(out, tile):
-        v_t, seg_ids, out_node = tile
-        partial_sums = jax.ops.segment_sum(
+    return _window_scan(
+        lambda v_t, seg_ids: jax.ops.segment_sum(
             v_t, seg_ids, num_segments=segments_per_tile
-        )
-        out = out.at[out_node].add(partial_sums)
-        return out, None
-
-    out, _ = jax.lax.scan(body, out, (v, dplan.seg_ids, dplan.out_node))
-    return out[:num_nodes]
+        ),
+        (v, dplan.seg_ids),
+        dplan,
+        num_nodes=num_nodes,
+        segments_per_tile=segments_per_tile,
+        like=values,
+        op="sum",
+    )
 
 
 def aggregate_mixed_precision(

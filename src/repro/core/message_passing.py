@@ -27,6 +27,7 @@ from repro.core.aggregation import (
     aggregate_edge_tiles,
     aggregate_mixed_precision,
     edge_segment_sum_tiles,
+    live_rows,
     segment_max_edge_tiles,
     tile_edge_coeff,
     to_device_plan,
@@ -649,6 +650,9 @@ class AmpleEngine:
         # and activation scale/zero-points are calibrated once per (plan,
         # call-site) and reused on warm requests — see begin_forward().
         self._dplan_cache: Dict[str, Dict] = {}
+        # mode -> the ``age`` span's args, static per cached upload: live
+        # accumulator rows and tile windows (T·S), summed over the plans.
+        self._age_rows: Dict[str, Dict[str, int]] = {}
         self._act_qp: Dict[tuple, QuantParams] = {}
         self._forward_active = False
         self._agg_slot = 0
@@ -753,6 +757,12 @@ class AmpleEngine:
             isinstance(d.gather_idx, jax.core.Tracer) for d in dplans.values()
         ):
             self._dplan_cache[mode] = dplans
+            self._age_rows[mode] = {
+                "rows": sum(live_rows(p) for p in plans.values()),
+                "window_rows": sum(
+                    p.num_tiles * p.segments_per_tile for p in plans.values()
+                ),
+            }
         return dplans
 
     def _require_edge_ids(self, mode: str, plans: Mapping[str, sched.EdgeTilePlan]) -> None:
@@ -921,10 +931,15 @@ class AmpleEngine:
         aggregates all heads in one tile scan (each head's column bitwise-
         equal to its solo 1-D run on the jnp path).
 
-        Recorded as one ``age`` span; engines override ``_aggregate``.
+        Recorded as one ``age`` span, with the plans' ``rows`` and
+        ``window_rows`` once they are uploaded; engines override
+        ``_aggregate``.
         """
-        with otrace.get_recorder().span("age", cat="engine", args={"mode": mode}):
-            return self._aggregate(x, mode=mode, edge_coeff=edge_coeff)
+        rec = otrace.get_recorder()
+        with rec.span("age", cat="engine", args={"mode": mode}) as span:
+            out = self._aggregate(x, mode=mode, edge_coeff=edge_coeff)
+            span.set(**self._age_rows.get(mode, {}))
+        return out
 
     def _aggregate(
         self,
@@ -1082,12 +1097,16 @@ class AmpleEngine:
         matches the oracle to float tolerance (tile-grouped summation
         re-associates), not bitwise.
 
-        Recorded as one ``age`` span; engines override
+        Recorded as one ``age`` span, with the plans' ``rows`` and
+        ``window_rows`` once they are uploaded; engines override
         ``_attention_aggregate``.
         """
-        with otrace.get_recorder().span("age", cat="engine", args={"mode": mode}):
-            return self._attention_aggregate(
+        rec = otrace.get_recorder()
+        with rec.span("age", cat="engine", args={"mode": mode}) as span:
+            out = self._attention_aggregate(
                 scores, z, mode=mode, leaky_slope=leaky_slope)
+            span.set(**self._age_rows.get(mode, {}))
+        return out
 
     def _attention_aggregate(
         self,

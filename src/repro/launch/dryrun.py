@@ -296,8 +296,10 @@ def _lower_gnn(cfg: ModelConfig, rec: Dict, *, multi_pod: bool) -> Dict:
     specs, meta = gnn_input_specs(cfg)
     n, s = meta["num_nodes"], meta["segments_per_tile"]
 
-    def gnn_step(x, gather_idx, coeff, seg_ids, out_node, edge_ids, w1, w2):
-        dplan = DeviceTilePlan(gather_idx, coeff, seg_ids, out_node, edge_ids)
+    def gnn_step(x, gather_idx, coeff, seg_ids, out_node, row_start, node_row,
+                 edge_ids, w1, w2):
+        dplan = DeviceTilePlan(gather_idx, coeff, seg_ids, out_node, row_start,
+                               node_row, edge_ids)
         m = aggregate_edge_tiles(x, dplan, num_nodes=n, segments_per_tile=s)
         h = jax.nn.relu(m @ w1)
         m2 = aggregate_edge_tiles(h, dplan, num_nodes=n, segments_per_tile=s)
@@ -309,11 +311,14 @@ def _lower_gnn(cfg: ModelConfig, rec: Dict, *, multi_pod: bool) -> Dict:
         "coeff": NamedSharding(mesh, P(dp, None)),
         "seg_ids": NamedSharding(mesh, P(dp, None)),
         "out_node": NamedSharding(mesh, P(dp, None)),
+        "row_start": NamedSharding(mesh, P(dp)),
+        "node_row": NamedSharding(mesh, P(None)),
         "edge_ids": NamedSharding(mesh, P(dp, None)),
         "w1": NamedSharding(mesh, P(None, "model")),
         "w2": NamedSharding(mesh, P("model", None)),
     }
-    ks = ["x", "gather_idx", "coeff", "seg_ids", "out_node", "edge_ids", "w1", "w2"]
+    ks = ["x", "gather_idx", "coeff", "seg_ids", "out_node", "row_start",
+          "node_row", "edge_ids", "w1", "w2"]
     args = [specs[k] for k in ks]
     in_sh = tuple(sh[k] for k in ks)
     t0 = time.time()

@@ -77,6 +77,8 @@ def gnn_input_specs(cfg: ModelConfig, *, dataset: str = "yelp",
         "coeff": _sds((t, edges_per_tile), jnp.float32),
         "seg_ids": _sds((t, edges_per_tile), jnp.int32),
         "out_node": _sds((t, s), jnp.int32),
+        "row_start": _sds((t,), jnp.int32),
+        "node_row": _sds((n,), jnp.int32),
         "edge_ids": _sds((t, edges_per_tile), jnp.int32),
         "w1": _sds((cfg.d_model, cfg.d_ff), jnp.float32),
         "w2": _sds((cfg.d_ff, cfg.vocab_size), jnp.float32),

@@ -599,6 +599,9 @@ def test_warm_request_spans_nest_on_the_profilers_clock(tmp_path):
     assert len(by["request"]) == 1 and len(by["layer"]) == 2
     assert by["request"][0][3]["padded_nodes"] == "384"
     assert by["request"][0][3]["cache_hit"] in ("1", "True")
+    # the AGE spans carry the segment-row window's occupancy
+    for age in by["age"]:
+        assert 0 < int(age[3]["rows"]) <= int(age[3]["window_rows"])
     # siblings of one parent do not overlap, and run in the served order
     order = [by[n][0] for n in ("validate", "plan", "pad", "execute")]
     assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))

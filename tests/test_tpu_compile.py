@@ -141,6 +141,8 @@ def test_jnp_age_compiles_at_reddit_scale(one_chip):
         coeff=tile(jnp.float32),
         seg_ids=tile(jnp.int32),
         out_node=tile(jnp.int32),
+        row_start=jax.ShapeDtypeStruct((t,), jnp.int32, sharding=one_chip),
+        node_row=jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
         edge_ids=None,
     )
     x = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
