@@ -2,7 +2,8 @@
 inference over Table-4 graphs.
 
 One registered config per Table-3 model — plus ``ample-gat``, the runtime-
-coefficient arch the event-driven pipeline unlocks beyond the paper — all
+coefficient arch the event-driven pipeline unlocks beyond the paper, and
+``ample-sage-pool``, GraphSAGE's max-pooling aggregator at Reddit's widths — all
 family="gnn" and dispatched through the unified model API (models/api.py ->
 models/gnn/api.py). d_model carries the feature width, d_ff the hidden width
 and vocab_size the class count (see launch/dryrun.py for the GNN input
@@ -11,6 +12,7 @@ Degree-Quant policy, ``gnn_heads`` the GAT attention heads (hidden widths
 must divide by it). The FULL configs are Yelp-scale (717k nodes, 300
 features, 100 classes); the REDUCED ones smoke-test on CPU.
 """
+import dataclasses
 import functools
 
 from repro.configs.base import ModelConfig, register
@@ -55,3 +57,19 @@ for _arch in ("gcn", "gin", "sage", "gat"):
         functools.partial(_full, _arch),
         functools.partial(_reduced, _arch),
     )
+
+
+# GraphSAGE-pool at its Reddit widths (arXiv:1706.02216; the reference code's
+# defaults): 602 features -> two layers of 128 + 128 concatenated -> 41
+# classes, pool width 512 (``model_size="small"``) carried by d_ff.
+register(
+    "ample-sage-pool",
+    lambda: dataclasses.replace(
+        _full("sage_pool"), name="ample-sage-pool",
+        d_model=602, gnn_hidden=(256, 256), d_ff=512, vocab_size=41,
+    ),
+    lambda: dataclasses.replace(
+        _reduced("sage_pool"), name="ample-sage-pool",
+        gnn_hidden=(16, 16), d_ff=24,
+    ),
+)

@@ -76,7 +76,7 @@ class ModelConfig:
     encoder_layers: int = 0  # >0 => encoder-decoder (seamless)
 
     # --- GNN (family="gnn"): drives models/gnn/api.py ---
-    gnn_arch: str = "gcn"  # gcn | gin | sage | gat (registry key)
+    gnn_arch: str = "gcn"  # gcn | gin | sage | gat | sage_pool (registry key)
     gnn_hidden: Tuple[int, ...] = ()  # explicit hidden widths; () -> (d_ff,)*(L-1)
     gnn_agg: str = ""  # aggregation coefficient mode override ("" = arch default)
     gnn_precision: str = "mixed"  # mixed (Degree-Quant int8/float) | float

@@ -5,10 +5,11 @@ Three execution paths mirror the paper's comparison:
 * ``aggregate_edge_tiles``  — event-driven path (AMPLE): ``lax.scan`` over the
   planner's dense edge tiles; each step gathers a tile of neighbour embeddings
   (HBM→VMEM stream in the Pallas version), reduces by local segment, and
-  adds the partial results into the tile's window of a segment-row
-  accumulator (partial-response combining). Compute ∝ E.
-* ``aggregate_bucket_plan`` — degree-bucketed padding (≤2× waste); the only
-  path supporting ``max`` aggregation.
+  adds (``op="sum"``) or maxes (``op="max"``, feature-wise, jnp path only)
+  the partial results into the tile's window of a segment-row accumulator
+  (partial-response combining). Compute ∝ E.
+* ``aggregate_bucket_plan`` — degree-bucketed padding (≤2× waste); sum, mean
+  or max.
 * ``aggregate_padded_plan`` — HyGCN-style double-buffer baseline, one padded
   dense batch at a time; its wasted lanes are the pipeline gaps AMPLE removes.
 
@@ -16,7 +17,9 @@ All paths produce identical results (property-tested); they differ only in
 lane economics, which the benchmarks measure.
 
 The per-edge ``coeff`` folds the aggregation function into the plan:
-sum → 1, mean → 1/deg, GCN → 1/√(d̂_i d̂_j). Invalid lanes carry coeff 0.
+sum → 1, mean → 1/deg, GCN → 1/√(d̂_i d̂_j). Invalid lanes carry coeff 0, and
+every mode gives a real edge a nonzero coeff, so a max reads the coeff as the
+lane mask alone.
 """
 from __future__ import annotations
 
@@ -180,7 +183,9 @@ def tile_edge_coeff(
     return padded[idx]
 
 
-@partial(jax.jit, static_argnames=("num_nodes", "segments_per_tile", "use_kernel"))
+@partial(
+    jax.jit, static_argnames=("num_nodes", "segments_per_tile", "use_kernel", "op")
+)
 def aggregate_edge_tiles(
     x: jnp.ndarray,
     dplan: DeviceTilePlan,
@@ -190,8 +195,16 @@ def aggregate_edge_tiles(
     use_kernel: bool = False,
     edge_coeff: Optional[jnp.ndarray] = None,
     out_init: Optional[jnp.ndarray] = None,
+    op: str = "sum",
 ) -> jnp.ndarray:
     """Event-driven aggregation: scan tiles, segment-reduce, window-add.
+
+    ``op="max"`` is the feature-wise neighbour max (GraphSAGE-pool): each
+    step gathers its tile's rows, sets padding lanes (static coeff 0) to
+    −inf, takes a segment max into ``[S, …]`` and maxes it into the window,
+    so a node split across tiles combines by max. The plan's coefficients
+    are read as the lane mask only. A node that receives no message reads 0.
+    jnp path only, without ``edge_coeff`` or ``out_init``.
 
     ``use_kernel`` routes the per-tile reduction through the Pallas AGE kernel
     (kernels/segment_agg); the default path is pure jnp and serves as its
@@ -215,6 +228,39 @@ def aggregate_edge_tiles(
     where the interior scan left off, so split == unsplit bitwise. jnp path
     only (the Pallas kernel owns its accumulator).
     """
+    if op == "max":
+        if use_kernel:
+            raise ValueError(
+                "op='max' has no Pallas AGE kernel (use_kernel): the kernel "
+                "only sums; run the max on the jnp path"
+            )
+        if edge_coeff is not None or out_init is not None:
+            raise ValueError(
+                "op='max' takes neither edge_coeff nor out_init: the max "
+                "reads the plan's coefficients as a lane mask and runs unsplit"
+            )
+
+        def partial_maxes(gather_idx, coeff, seg_ids):
+            gathered = x[gather_idx]  # [E, D] or [E, H, dh]
+            real = (coeff != 0).reshape(coeff.shape + (1,) * (gathered.ndim - 1))
+            return jax.ops.segment_max(
+                jnp.where(real, gathered, -jnp.inf),
+                seg_ids,
+                num_segments=segments_per_tile,
+            )  # [S, …]
+
+        return _window_scan(
+            partial_maxes,
+            (dplan.gather_idx, dplan.coeff, dplan.seg_ids),
+            dplan,
+            num_nodes=num_nodes,
+            segments_per_tile=segments_per_tile,
+            like=x,
+            op="max",
+            uncovered=0.0,
+        )
+    if op != "sum":
+        raise ValueError(f"unknown aggregation op {op!r}; have 'sum', 'max'")
     coeff = dplan.coeff
     if edge_coeff is not None:
         tc = tile_edge_coeff(dplan, edge_coeff)  # [T, E] or [T, E, H]
@@ -298,6 +344,7 @@ def _window_scan(
     like: jnp.ndarray,
     op: str,
     init: Optional[jnp.ndarray] = None,
+    uncovered: Optional[float] = None,
 ) -> jnp.ndarray:
     """Scan the tiles, combining each tile's ``[S, …]`` partial results into
     a window of a segment-row accumulator; return it gathered to node order.
@@ -305,7 +352,10 @@ def _window_scan(
     The accumulator holds ``num_nodes + S`` rows (``segment_rows``), set to
     the op's identity (0 for ``sum``, −inf for ``max``) or, for the
     rows of ``init``'s nodes, seeded from ``init`` (the continuation of a
-    split interior/boundary run). Each step reads the S rows at
+    split interior/boundary run). ``uncovered`` instead seeds the spare rows
+    of the nodes no tile covers, which lie past the last window (from
+    ``max(row_start) + S`` on), so those nodes read it and no window
+    touches it. Each step reads the S rows at
     ``row_start[t]``, combines the tile's partials in and writes them back:
     one contiguous in-place update where a scatter into node space took one
     row update per segment. Sentinel segments are masked to the exact
@@ -318,6 +368,13 @@ def _window_scan(
     trailing = like.shape[1:]
     combine, fill, blank = _WINDOW_OPS[op]
     acc = jnp.full((num_nodes + s,) + trailing, fill, like.dtype)
+    if uncovered is not None:
+        spare = jnp.arange(num_nodes + s) >= jnp.max(dplan.row_start, initial=0) + s
+        acc = jnp.where(
+            spare.reshape((-1,) + (1,) * len(trailing)),
+            jnp.asarray(uncovered, like.dtype),
+            acc,
+        )
     if init is not None:
         acc = acc.at[dplan.node_row].set(init.astype(like.dtype))
 
@@ -464,13 +521,17 @@ def aggregate_mixed_precision(
     qp: Optional[QuantParams] = None,
     device_plans: Optional[Dict[str, DeviceTilePlan]] = None,
     edge_coeff: Optional[jnp.ndarray] = None,
+    op: str = "sum",
 ) -> jnp.ndarray:
     """Mixed-precision AGE: the float plan consumes fp32 embeddings; the int8
     plan consumes int8-quantized embeddings (4× lighter gather traffic — the
     bandwidth win the paper banks on), dequantized on-chip before accumulate.
 
     The two streams write disjoint node sets, so the combined output is just
-    the sum of the two scatter targets.
+    the sum of the two scatter targets. That holds for ``op="max"`` too:
+    each stream's max gives 0 on the nodes it does not cover, and the int8
+    stream maxes the dequantized messages (rounding is monotone, so the max
+    of the rounded rows is the rounded max).
 
     ``qp`` overrides the activation scale/zero-point (per-call min/max
     calibration otherwise) — the engine passes its per-plan static quant state
@@ -498,6 +559,7 @@ def aggregate_mixed_precision(
             segments_per_tile=p.segments_per_tile,
             use_kernel=use_kernel,
             edge_coeff=edge_coeff,
+            op=op,
         )
     if "int8" in plans:
         p = plans["int8"]
@@ -512,6 +574,7 @@ def aggregate_mixed_precision(
             segments_per_tile=p.segments_per_tile,
             use_kernel=use_kernel,
             edge_coeff=edge_coeff,
+            op=op,
         )
     for tag, p in plans.items():
         if tag not in ("float", "int8"):

@@ -653,6 +653,7 @@ class AmpleEngine:
         # mode -> the ``age`` span's args, static per cached upload: live
         # accumulator rows and tile windows (T·S), summed over the plans.
         self._age_rows: Dict[str, Dict[str, int]] = {}
+        self._empty_row_count: Optional[int] = None  # see _empty_rows
         self._act_qp: Dict[tuple, QuantParams] = {}
         self._forward_active = False
         self._agg_slot = 0
@@ -914,8 +915,16 @@ class AmpleEngine:
         *,
         mode: str = "sum",
         edge_coeff: Optional[jnp.ndarray] = None,
+        op: str = "sum",
     ) -> jnp.ndarray:
         """Event-driven mixed-precision aggregation of node embeddings.
+
+        ``op="max"`` takes the feature-wise max over each node's in-
+        neighbours (GraphSAGE-pool) through the same tiles, uploaded once
+        for ``mode``: the coefficients serve as the lane mask only. A node
+        with no in-edge reads 0. Dense embeddings on the jnp path only: the
+        streamed executor, the Pallas kernel (``use_kernel``) and the
+        sharded engine sum, and refuse it with ``ValueError``.
 
         ``x`` may be a ``memory.StreamedFeatures`` handle instead of a dense
         matrix: aggregation then runs chunk-streamed through the prefetcher
@@ -931,15 +940,29 @@ class AmpleEngine:
         aggregates all heads in one tile scan (each head's column bitwise-
         equal to its solo 1-D run on the jnp path).
 
-        Recorded as one ``age`` span, with the plans' ``rows`` and
-        ``window_rows`` once they are uploaded; engines override
-        ``_aggregate``.
+        Recorded as one ``age`` span with args ``mode`` and ``op``, the
+        plans' ``rows`` and ``window_rows`` once they are uploaded and, for
+        a max, ``empty_rows`` (real nodes with no in-edge, which read 0);
+        engines override ``_aggregate``.
         """
         rec = otrace.get_recorder()
-        with rec.span("age", cat="engine", args={"mode": mode}) as span:
-            out = self._aggregate(x, mode=mode, edge_coeff=edge_coeff)
+        with rec.span("age", cat="engine", args={"mode": mode, "op": op}) as span:
+            out = self._aggregate(x, mode=mode, edge_coeff=edge_coeff, op=op)
             span.set(**self._age_rows.get(mode, {}))
+            if op == "max":
+                span.set(empty_rows=self._empty_rows())
         return out
+
+    def _empty_rows(self) -> int:
+        """Real nodes (those of a precision group, so not size-class
+        padding) with no in-edge; static per engine, counted once."""
+        if self._empty_row_count is None:
+            real = np.concatenate(
+                [np.zeros(0, np.int64)]
+                + [np.asarray(ids, np.int64) for ids in self.node_groups.values()]
+            )
+            self._empty_row_count = int((self.graph.degrees[real] == 0).sum())
+        return self._empty_row_count
 
     def _aggregate(
         self,
@@ -947,8 +970,14 @@ class AmpleEngine:
         *,
         mode: str = "sum",
         edge_coeff: Optional[jnp.ndarray] = None,
+        op: str = "sum",
     ) -> jnp.ndarray:
         if isinstance(x, _streamed_features_type()):
+            if op != "sum":
+                raise ValueError(
+                    f"op={op!r} needs dense embeddings: the streamed AGE "
+                    "(feature budget) only sums; lift the feature budget"
+                )
             if edge_coeff is not None:
                 raise ValueError(
                     "runtime edge coefficients require dense embeddings; the "
@@ -988,6 +1017,7 @@ class AmpleEngine:
                 qp=qp,
                 device_plans=dplans,
                 edge_coeff=edge_coeff,
+                op=op,
             )
         p = plans["float"]
         return aggregate_edge_tiles(
@@ -997,6 +1027,7 @@ class AmpleEngine:
             segments_per_tile=p.segments_per_tile,
             use_kernel=self.cfg.use_kernel,
             edge_coeff=edge_coeff,
+            op=op,
         )
 
     # ------------------------------------------------ runtime coefficients

@@ -754,7 +754,14 @@ class ShardedAmpleEngine(AmpleEngine):
         *,
         mode: str = "sum",
         edge_coeff: Optional[jnp.ndarray] = None,
+        op: str = "sum",
     ) -> jnp.ndarray:
+        if op != "sum":
+            raise ValueError(
+                f"op={op!r} is not served by the sharded AGE: it combines "
+                "the shards' partial results by addition; serve the model "
+                "on one AmpleEngine (gnn_num_shards=1)"
+            )
         splan = self.sharded_plan
         if edge_coeff is not None:
             edge_coeff = jnp.asarray(edge_coeff, jnp.float32)

@@ -1,7 +1,7 @@
 """GNN model registry — the family="gnn" half of the unified model API.
 
-Every arch (gcn / gin / sage / gat) registers an ``ArchSpec`` with three
-uniform, config-driven entry points:
+Every arch (gcn / gin / sage / gat / sage_pool) registers an ``ArchSpec``
+with three uniform, config-driven entry points:
 
     init(cfg, key)                     -> params
     apply(cfg, params, engine, x)      -> node outputs (through AmpleEngine)

@@ -30,6 +30,7 @@ from repro.core.aggregation import (
     to_device_plan,
 )
 from repro.core.degree_quant import DegreeQuantConfig, inference_precision_tags
+from repro.core.quantization import compute_scale_zp, dequantize, quantize
 from repro.core.scheduler import concat_tile_plans, pack_tiles_by_chunk, split_plan_by_halo
 from repro.graphs import disjoint_union
 from repro.graphs.csr import Graph, gcn_norm_coeffs
@@ -322,3 +323,108 @@ def test_segment_rows_rejects_broken_invariant():
     out_node[t, 0] = g.num_nodes
     with pytest.raises(ValueError, match="prefix"):
         to_device_plan(dataclasses.replace(plan, out_node=out_node))
+
+
+# ------------------------------------------------ feature-wise max (op="max")
+#
+# The reference is a node-space segment max over the same gathered rows:
+# every real lane's row goes to its destination node, padding lanes (coeff
+# 0) are left out, and nodes no lane reaches read 0.
+
+
+def _node_space_max(x, plan):
+    x = np.asarray(x)
+    n = plan.num_nodes
+    real = plan.coeff != 0
+    dst = plan.out_node[np.arange(plan.num_tiles)[:, None], plan.seg_ids][real]
+    out = jax.ops.segment_max(jnp.asarray(x[plan.gather_idx[real]]),
+                              jnp.asarray(dst), num_segments=n)
+    covered = np.zeros(n, bool)
+    covered[dst] = True
+    return jnp.where(covered.reshape((n,) + (1,) * (x.ndim - 1)), out, 0.0)
+
+
+@pytest.mark.parametrize(
+    "kind", ["lognormal_hubs", "degree1_tail", "empty", "union", "halo_split", "packed"]
+)
+def test_window_max_matches_node_space_segment_max_bitwise(kind):
+    g, plans = _window_plans(kind)
+    n = g.num_nodes
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(rng.standard_normal((n, 5)).astype(np.float32) - 2.0)  # mostly < 0
+    for p in plans:  # halo_split: interior and boundary, each on its own nodes
+        out = aggregate_edge_tiles(x, to_device_plan(p), num_nodes=n,
+                                   segments_per_tile=p.segments_per_tile, op="max")
+        _assert_same(out, _node_space_max(x, p))
+        uncovered = np.setdiff1d(np.arange(n), p.out_node[p.out_node != n])
+        assert (np.asarray(out)[uncovered] == 0).all()
+        if kind in ("empty", "union"):
+            assert uncovered.size > 0  # nodes with no in-edge read 0
+
+
+def test_window_max_splits_hubs_and_takes_heads():
+    g, (plan,) = _window_plans("lognormal_hubs")
+    live = plan.out_node[plan.out_node != g.num_nodes]
+    nodes, segments = np.unique(live, return_counts=True)
+    split = nodes[segments > 1]
+    assert split.size > 0  # hubs whose lanes run across several tiles
+    x = jnp.asarray(np.random.default_rng(3).standard_normal((g.num_nodes, 2, 3)),
+                    jnp.float32)
+    out = aggregate_edge_tiles(x, to_device_plan(plan), num_nodes=g.num_nodes,
+                               segments_per_tile=plan.segments_per_tile, op="max")
+    _assert_same(out, _node_space_max(x, plan))
+    nbr_max = np.stack([np.asarray(x)[g.neighbors(v)].max(axis=0) for v in split])
+    _assert_same(np.asarray(out)[split], nbr_max)
+
+
+def test_window_max_ignores_non_finite_rows_only_padding_reads():
+    """Padding lanes gather row 0, some of them inside a live segment (S < E);
+    a NaN or inf there reaches no output row."""
+    rng = np.random.default_rng(2)
+    g = _csr([40, 23] + [1] * 70, rng)
+    g = Graph(indptr=g.indptr, indices=np.maximum(g.indices, 1), num_nodes=g.num_nodes)
+    plan = build_edge_tile_plan(g, edges_per_tile=16, segments_per_tile=8)
+    padding = plan.coeff == 0
+    assert (plan.gather_idx[padding] == 0).all() and (plan.gather_idx[~padding] != 0).all()
+    in_live = padding & (plan.out_node[np.arange(plan.num_tiles)[:, None], plan.seg_ids]
+                         != g.num_nodes)
+    assert in_live.any()
+    dp = to_device_plan(plan)
+    x = rng.standard_normal((g.num_nodes, 4)).astype(np.float32)
+    for bad in (np.nan, np.inf, -np.inf):
+        x[0] = bad
+        out = aggregate_edge_tiles(jnp.asarray(x), dp, num_nodes=g.num_nodes,
+                                   segments_per_tile=8, op="max")
+        assert np.isfinite(np.asarray(out)).all()
+        _assert_same(out, _node_space_max(x, plan))
+
+
+def test_mixed_precision_max_rounds_only_int8_messages():
+    g = make_lognormal_graph(300, 6.0, sigma=1.4, seed=9)
+    tags = inference_precision_tags(g, DegreeQuantConfig(float_ratio=0.05))
+    plans = build_mixed_precision_plans(g, tags, edges_per_tile=32)
+    x = jnp.asarray(np.random.default_rng(4).standard_normal((300, 6)), jnp.float32)
+    qp = compute_scale_zp(x, symmetric=True)
+    out = np.asarray(aggregate_mixed_precision(x, plans, num_nodes=300, qp=qp, op="max"))
+    xdq = np.asarray(dequantize(quantize(x, qp), qp))
+    want = np.zeros_like(out)
+    for v in range(300):
+        src = g.neighbors(v)
+        if src.size:
+            want[v] = (np.asarray(x) if tags[v] == "float" else xdq)[src].max(axis=0)
+    assert (tags == "int8").any() and (tags == "float").any()
+    _assert_same(out, want)
+
+
+def test_max_refuses_the_kernel_coefficients_and_unknown_ops():
+    g, (plan,) = _window_plans("degree1_tail")
+    dp, n = to_device_plan(plan), g.num_nodes
+    x = jnp.ones((n, 4), jnp.float32)
+    with pytest.raises(ValueError, match="Pallas AGE kernel"):
+        aggregate_edge_tiles(x, dp, num_nodes=n, segments_per_tile=8, op="max",
+                             use_kernel=True)
+    with pytest.raises(ValueError, match="edge_coeff"):
+        aggregate_edge_tiles(x, dp, num_nodes=n, segments_per_tile=8, op="max",
+                             edge_coeff=jnp.ones(g.num_edges))
+    with pytest.raises(ValueError, match="unknown aggregation op"):
+        aggregate_edge_tiles(x, dp, num_nodes=n, segments_per_tile=8, op="min")

@@ -647,3 +647,36 @@ def test_pinned_stamps_replace_entry_and_exit():
     (s,) = rec.spans()
     assert (s.t0, s.t1) == (1.0, 2.5)
     assert NULL_SPAN.stamps(1.0, 2.0) is NULL_SPAN
+
+
+def test_warm_sage_pool_age_spans_record_the_max(recorder):
+    """GraphSAGE-pool's AGE spans say which reduction ran and how many real
+    nodes had no in-edge (size-class padding rows are not counted), nested
+    under their layer as every arch's are."""
+    from repro.graphs.csr import Graph
+
+    cfg = get_config("ample-sage-pool", reduced=True)
+    base = _graph(n=300, dim=cfg.d_model)
+    cut = np.isin(np.repeat(np.arange(300), base.degrees), [3, 50, 299])
+    deg = np.bincount(np.repeat(np.arange(300), base.degrees)[~cut], minlength=300)
+    g = Graph(indptr=np.concatenate([[0], np.cumsum(deg)]), indices=base.indices[~cut],
+              num_nodes=300, features=base.features)
+    empty = int((g.degrees == 0).sum())
+    assert empty >= 3
+    eng = GNNServeEngine(cfg, key=jax.random.PRNGKey(0),
+                         union_node_bucket=128, union_edge_bucket=512)
+    eng.infer(g, g.features)  # warm
+    recorder.clear()
+    r = eng.infer(g, g.features)
+    assert r.cache_hit
+    spans = recorder.spans()
+    by_sid = {s.sid: s for s in spans}
+    ages = [s for s in spans if s.name == "age"]
+    assert len(ages) == 2
+    for age in ages:
+        assert age.args["op"] == "max" and age.args["mode"] == "sum"
+        assert age.args["empty_rows"] == empty
+        assert 0 < age.args["rows"] <= age.args["window_rows"]
+        assert by_sid[age.parent].name == "layer"
+    layers = sorted(s.args["index"] for s in spans if s.name == "layer")
+    assert layers == [0, 1, 2]  # two GNN layers, then the head

@@ -152,3 +152,30 @@ def test_jnp_age_compiles_at_reddit_scale(one_chip):
     mem = c.memory_analysis()
     used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < V5E_HBM_BYTES
+
+
+def test_jnp_age_max_compiles_at_reddit_scale(one_chip):
+    """ample-sage-pool's feature-wise max at reddit scale: 512-wide pooled
+    rows over about 95,000 tiles fit one chip."""
+    from repro.core.aggregation import DeviceTilePlan, aggregate_edge_tiles
+
+    n, d, t, e = 232_965, 512, 95_000, 256
+    tile = lambda dt: jax.ShapeDtypeStruct((t, e), dt, sharding=one_chip)
+    plan = DeviceTilePlan(
+        gather_idx=tile(jnp.int32),
+        coeff=tile(jnp.float32),
+        seg_ids=tile(jnp.int32),
+        out_node=tile(jnp.int32),
+        row_start=jax.ShapeDtypeStruct((t,), jnp.int32, sharding=one_chip),
+        node_row=jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+        edge_ids=None,
+    )
+    x = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
+    c = aggregate_edge_tiles.lower(
+        x, plan, num_nodes=n, segments_per_tile=e, op="max"
+    ).compile()
+    mem = c.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert 0 < used < V5E_HBM_BYTES
+    print(f"max scan: argument {mem.argument_size_in_bytes} output "
+          f"{mem.output_size_in_bytes} temp {mem.temp_size_in_bytes} bytes")
