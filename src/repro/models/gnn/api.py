@@ -19,6 +19,7 @@ import dataclasses
 import importlib
 from typing import Callable, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
@@ -191,18 +192,18 @@ def gnn_forward(params: Dict, cfg: ModelConfig, batch: Dict) -> Tuple[jnp.ndarra
 
     ``batch`` carries ``graph`` (a CSR Graph) and ``features`` f32[N, D];
     callers holding a compiled engine (the serving path) pass it as
-    ``batch["engine"]`` to skip plan compilation. ``features`` may also be a
-    ``memory.StreamedFeatures`` handle — the out-of-core path: the feature
-    matrix stays host-resident and the engine streams it chunk-wise under
-    the handle's budget. Returns ``(logits, aux)`` with logits
-    f32[N, num_classes], matching the LM tuple contract so ``loss_fn``
-    works unchanged for node classification.
+    ``batch["engine"]`` to skip plan compilation. ``features`` may be a
+    device array (nothing to upload), or a ``memory.StreamedFeatures``
+    handle — the out-of-core path: the feature matrix stays host-resident
+    and the engine streams it chunk-wise under the handle's budget. Returns
+    ``(logits, aux)`` with logits f32[N, num_classes], matching the LM tuple
+    contract so ``loss_fn`` works unchanged for node classification.
     """
     from repro.memory.prefetcher import StreamedFeatures
 
     feats = batch["features"]
-    if isinstance(feats, StreamedFeatures):
-        x = feats
+    if isinstance(feats, (StreamedFeatures, jax.Array)):
+        x = feats  # host-resident stream, or already on the device
     else:
         with otrace.get_recorder().span(
             "upload", cat="model", args={"bytes": getattr(feats, "nbytes", 0)}

@@ -20,6 +20,7 @@ the cacheable artifact:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import os
 import time
@@ -54,6 +55,13 @@ from repro.observe import metrics as ometrics
 from repro.observe import trace as otrace
 
 __all__ = ["GNNRequest", "GNNResponse", "GNNServeEngine", "request_stamp"]
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def pad_rows(x: jax.Array, num_rows: int) -> jax.Array:
+    """Zero rows appended on the device up to ``num_rows`` (one program per
+    ``(rows, num_rows, width)``)."""
+    return jnp.pad(x, ((0, num_rows - x.shape[0]), (0, 0)))
 
 
 def request_stamp() -> float:
@@ -353,6 +361,8 @@ class GNNServeEngine:
                 "halo_bytes",
                 "halo_ms",
                 "halo_wait_ms",
+                "pad_host_bytes",
+                "device_pads",
             ),
             float_keys=("stall_ms", "copy_ms", "halo_ms", "halo_wait_ms"),
         )
@@ -693,19 +703,42 @@ class GNNServeEngine:
             axis=0,
         )
 
+    def _count_pad(self, span, rows: int, padded_rows: int, host_bytes: int) -> None:
+        """The front's ``pad`` step: ``host_bytes`` is what it copied on the
+        host (0 when the zero rows are left to the device, or none are due)."""
+        self.stats["pad_host_bytes"] += host_bytes
+        span.set(rows=rows, padded_rows=padded_rows, host_bytes=host_bytes)
+
+    def _pad_on_device(self, features: np.ndarray, num_rows: int) -> jax.Array:
+        """Upload the real rows and append the size class's zero rows on the
+        device. Padding rows are in no precision group, so they only have to
+        exist; the unpadded device array dies with this frame, before the
+        first layer."""
+        rec = otrace.get_recorder()
+        with rec.span("upload", cat="model", args={"bytes": features.nbytes}):
+            x = jnp.asarray(features)
+        with rec.span("device_pad", cat="serve",
+                      args={"rows": features.shape[0], "padded_rows": num_rows}):
+            padded = pad_rows(x, num_rows)
+        self.stats["device_pads"] += 1
+        return padded
+
     # ------------------------------------------------- out-of-core streaming
-    def _stream_eligible(self, engine: AmpleEngine, features: np.ndarray) -> bool:
-        """Stream iff a budget is set, the matrix exceeds it, and the plan
-        executes on the plain single-device engine (the sharded executor
-        gathers per-shard row sets and is served in-memory). Kernel-routed
-        engines (``use_kernel``) are excluded: the streamed executors are
-        the jnp oracle, and Pallas vs oracle can differ by an int8 rounding
-        step — streaming there would break the bitwise guarantee."""
+    def _stream_eligible(
+        self, engine: AmpleEngine, features: np.ndarray, rows: int
+    ) -> bool:
+        """Stream iff a budget is set, the matrix padded to ``rows`` exceeds
+        it, and the plan executes on the plain single-device engine (the
+        sharded executor gathers per-shard row sets and is served
+        in-memory). Kernel-routed engines (``use_kernel``) are excluded: the
+        streamed executors are the jnp oracle, and Pallas vs oracle can
+        differ by an int8 rounding step — streaming there would break the
+        bitwise guarantee."""
         return (
             self.feature_budget_bytes > 0
             and type(engine) is AmpleEngine
             and not self.engine_cfg.use_kernel
-            and features.nbytes > self.feature_budget_bytes
+            and rows * features.shape[1] * features.itemsize > self.feature_budget_bytes
         )
 
     def _feature_stream(
@@ -778,13 +811,14 @@ class GNNServeEngine:
         telemetry lands in ``stats`` and on the response. ``cache_store``
         is False on the batch path (per-call union matrices never repeat);
         ``store_key`` carries the caller-held array identity when
-        ``features`` is a per-call padded copy.
+        ``features`` is a per-call padded copy. In-memory ``features`` with
+        fewer rows than ``prepared`` get their zero rows on the device.
         """
         cfg = dataclasses.replace(self.cfg, gnn_arch=arch)
         self._last_stream = None
         self._last_halo = None
         batch_features = features
-        if self._stream_eligible(engine, features):
+        if self._stream_eligible(engine, features, prepared.num_nodes):
             sf = self._feature_stream(
                 features, cache_store=cache_store, store_key=store_key
             )
@@ -801,10 +835,14 @@ class GNNServeEngine:
             args={"arch": arch, "streamed": self._last_stream is not None},
         ) as span:
             t0 = time.perf_counter()
+            if self._last_stream is None and features.shape[0] < prepared.num_nodes:
+                batch_features = self._pad_on_device(features, prepared.num_nodes)
             y, _ = gnn_api.gnn_forward(
                 self.params, cfg,
                 {"graph": prepared, "features": batch_features, "engine": engine},
             )
+            # The device input dies with the forward's last use, not the wait.
+            del batch_features
             with rec.span("wait", cat="serve"):
                 y = jax.block_until_ready(y)
             with rec.span("fetch", cat="serve", args={"bytes": y.nbytes}):
@@ -934,8 +972,14 @@ class GNNServeEngine:
                 span.set(cache_hit=hit, plan_ms=plan_ms)
             request.set(cache_hit=hit, padded_nodes=prepared.num_nodes)
             if self.padded_unions:
-                with rec.span("pad", cat="serve"):
-                    features = self._pad_features(features, prepared.num_nodes)
+                with rec.span("pad", cat="serve") as span:
+                    rows, padded_rows = features.shape[0], prepared.num_nodes
+                    # A streamed store is built from host rows; otherwise
+                    # ``_run`` adds the zero rows on the device.
+                    if self._stream_eligible(engine, features, padded_rows):
+                        features = self._pad_features(features, padded_rows)
+                    self._count_pad(span, rows, padded_rows,
+                                    features.nbytes if features.shape[0] > rows else 0)
             y, run_ms = self._run(
                 arch, prepared, engine, features, store_key=original,
                 trace_id=trace_id,
@@ -1007,9 +1051,13 @@ class GNNServeEngine:
             request.set(cache_hit=hit,
                         nodes=sum(m.num_nodes for m in members),
                         padded_nodes=prepared.num_nodes)
-            with rec.span("pad", cat="serve"):
+            # The real row count follows the union's composition, so a device
+            # pad would compile a program per composition: pad on the host.
+            with rec.span("pad", cat="serve") as span:
                 features = self._pad_features(np.concatenate(feats, axis=0),
                                               prepared.num_nodes)
+                self._count_pad(span, sum(f.shape[0] for f in feats),
+                                prepared.num_nodes, features.nbytes)
             y, run_ms = self._run(
                 arch, prepared, engine, features, cache_store=False,
                 trace_id=batch_tid,

@@ -584,7 +584,7 @@ def test_warm_request_spans_nest_on_the_profilers_clock(tmp_path):
         by.setdefault(s[0], []).append(s)
     tree = {
         "request": {"validate", "plan", "pad", "execute"},
-        "execute": {"upload", "layer", "wait", "fetch"},
+        "execute": {"upload", "device_pad", "layer", "wait", "fetch"},
         "layer": {"fte", "age"},
     }
 
@@ -599,6 +599,13 @@ def test_warm_request_spans_nest_on_the_profilers_clock(tmp_path):
     assert len(by["request"]) == 1 and len(by["layer"]) == 2
     assert by["request"][0][3]["padded_nodes"] == "384"
     assert by["request"][0][3]["cache_hit"] in ("1", "True")
+    # the zero rows are added on the device, after the upload: no host copy
+    (pad,) = by["pad"]
+    assert (pad[3]["rows"], pad[3]["padded_rows"], pad[3]["host_bytes"]) == (
+        "300", "384", "0")
+    (upload,), (device_pad,) = by["upload"], by["device_pad"]
+    assert upload[2] <= device_pad[1] and device_pad[2] <= by["layer"][0][1]
+    assert device_pad[3]["padded_rows"] == "384"
     # the AGE spans carry the segment-row window's occupancy
     for age in by["age"]:
         assert 0 < int(age[3]["rows"]) <= int(age[3]["window_rows"])
